@@ -113,9 +113,10 @@ def main() -> None:
     ap.add_argument("--obs-report", default=None,
                     help="override the obs_report.json path (written next "
                          "to the BENCH json by default)")
-    ap.add_argument("--trace", default=None,
-                    help="also write a Chrome trace_event JSON of the "
-                         "run's span timeline")
+    ap.add_argument("--trace", default="",
+                    help="run the bench modules under the JAX profiler, "
+                         "writing its trace (host spans and device ops on "
+                         "one clock) under this directory")
     args = ap.parse_args()
 
     from repro.launch.cache import configure_compile_cache
@@ -149,17 +150,23 @@ def main() -> None:
         ]
     print("name,us_per_call,derived")
     rows, failures = [], 0
-    for tag, fn in modules:
-        try:
-            for r in fn():
-                print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
-                rows.append({"table": tag, **{k: r[k] for k in
-                                              ("name", "us_per_call",
-                                               "derived")}})
-        except Exception as e:
-            failures += 1
-            print(f"{tag}_FAILED,0,{type(e).__name__}: {e}", file=sys.stderr)
-            traceback.print_exc()
+    from repro.obs import get_obs, profiler_trace, write_obs_report
+    with profiler_trace(args.trace):
+        for tag, fn in modules:
+            try:
+                for r in fn():
+                    print(f"{r['name']},{r['us_per_call']:.1f},"
+                          f"{r['derived']}")
+                    rows.append({"table": tag, **{k: r[k] for k in
+                                                  ("name", "us_per_call",
+                                                   "derived")}})
+            except Exception as e:
+                failures += 1
+                print(f"{tag}_FAILED,0,{type(e).__name__}: {e}",
+                      file=sys.stderr)
+                traceback.print_exc()
+    if args.trace:
+        print(f"wrote a profiler trace under {args.trace}", file=sys.stderr)
     out = args.out or os.path.join(
         REPO, f"BENCH_{'smoke' if args.smoke else 'full'}.json")
     with open(out, "w") as f:
@@ -172,15 +179,11 @@ def main() -> None:
     # span timeline every bench module recorded into the global ring,
     # reduced to per-step overlap_frac + per-residency-class swap bytes —
     # the report Planner v2 consumes alongside analysis_report.json
-    from repro.obs import export_chrome_trace, get_obs, write_obs_report
     obs_path = args.obs_report or os.path.join(
         os.path.dirname(out) or ".", "obs_report.json")
     write_obs_report(obs_path, obs=get_obs(),
                      meta={"mode": "smoke" if args.smoke else "full"})
     print(f"wrote {obs_path}", file=sys.stderr)
-    if args.trace:
-        export_chrome_trace(get_obs().ring.events(), args.trace)
-        print(f"wrote {args.trace}", file=sys.stderr)
     if failures:
         sys.exit(1)
     if args.compare:

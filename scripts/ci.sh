@@ -86,19 +86,21 @@ python -m repro.analysis.calibrate --profile obs_report.json \
 echo "== observability smoke (DESIGN.md §12) =="
 # drive the instrumented train + serve paths with the JSONL sink on, then
 # assert the obs report carries the fields Planner v2 consumes: nonzero
-# swap spans, overlap_frac, per-residency-class swap bytes
+# swap spans, overlap_frac, per-residency-class swap bytes; and that the
+# serve run's profiler trace holds the engine's spans as host events
 rm -rf /tmp/ci_obs_ckpt  # stale checkpoints would resume past --steps
+rm -rf trace_smoke
 python -m repro.launch.train --arch olmo-1b --smoke --steps 2 --batch 2 \
     --seq 32 --ckpt-dir /tmp/ci_obs_ckpt --log-every 2 \
     --obs-jsonl /tmp/ci_obs_train.jsonl > /dev/null
 python -m repro.launch.serve --arch olmo-1b --smoke --requests 5 --slots 2 \
     --prompt-len 8 --gen 8 --page-size 4 --prefill-chunk 4 \
-    --obs-jsonl /tmp/ci_obs_serve.jsonl --trace trace_smoke.json \
+    --obs-jsonl /tmp/ci_obs_serve.jsonl --trace trace_smoke \
     --obs-report obs_report.json > /dev/null
 test -s /tmp/ci_obs_train.jsonl
 test -s /tmp/ci_obs_serve.jsonl
-test -s trace_smoke.json
 python - <<'EOF'
+import glob
 import json
 r = json.load(open("obs_report.json"))
 assert "overlap_frac" in r, "obs_report missing overlap_frac"
@@ -106,9 +108,13 @@ assert r["swap_spans"] > 0, "obs_report has no swap spans"
 assert r["classes"].get("kvcache", {}).get("bytes", 0) > 0, \
     "obs_report has no per-class swap bytes"
 assert r["per_step"] and all("overlap_frac" in row for row in r["per_step"])
-t = json.load(open("trace_smoke.json"))
-phs = {e["ph"] for e in t["traceEvents"]}
-assert {"M", "X"} <= phs, f"chrome trace missing span/meta events: {phs}"
+from jax.profiler import ProfileData
+paths = glob.glob("trace_smoke/plugins/profile/*/*.xplane.pb")
+assert paths, "serve --trace wrote no profiler trace"
+host = {e.name for p in ProfileData.from_file(paths[0]).planes
+        if p.name.startswith("/host:") for line in p.lines
+        for e in line.events}
+assert "engine.tick" in host, "profiler trace has no engine.tick span"
 EOF
 
 echo "== kernel tests, forced Pallas interpret =="

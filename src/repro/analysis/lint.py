@@ -30,10 +30,11 @@ Rules (all severity "error"; unwaived findings gate CI):
                               garbage on real hardware.
   RL007 obs-site-name         a string-literal site/metric name passed to
                               an obs call (span/instant/trace_event/
-                              counter/gauge/histogram/series) that is not
-                              a lowercase dotted identifier under a
-                              registered prefix (repro.obs.sites) — a
-                              typo'd site silently forks the timeline.
+                              counter/gauge/histogram/series) or to
+                              jax.named_scope that is not a lowercase
+                              dotted identifier under a registered prefix
+                              (repro.obs.sites) — a typo'd site silently
+                              forks the timeline.
 
 Waiver syntax — same line or the line above the finding:
 
@@ -66,9 +67,10 @@ HOT_FUNCS = {"engine.py": {"_tick"}, "trainer.py": {"train"}}
 TIMER_ATTRS = {"perf_counter", "monotonic"}
 CLAMP_NAMES = {"minimum", "clip"}
 # obs recording entry points for RL007: any string-literal first arg is a
-# site/metric name and must validate against repro.obs.sites
+# site/metric name and must validate against repro.obs.sites; a named
+# scope is the device-side site the benchmark reads from the trace
 OBS_CALLS = {"span", "instant", "trace_event", "counter", "gauge",
-             "histogram", "series"}
+             "histogram", "series", "named_scope"}
 
 
 def _func_name(call: ast.Call) -> Optional[str]:

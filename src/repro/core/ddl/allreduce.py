@@ -198,13 +198,16 @@ def ddl_reduce_tree(grads, cfg: DDLConfig, *, data_axis: str = "data",
         specs = [None] * len(leaves)
     efs = (error_feedback if error_feedback is not None else [None] * len(leaves))
     out, new_ef = [], []
-    for g, sp, ef in zip(leaves, specs, efs):
-        r, e = ddl_reduce_leaf(
-            g, data_axis=data_axis, pod_axis=pod_axis, data_size=data_size,
-            pod_size=pod_size, compress_dcn=cfg.compress_dcn,
-            topology_aware=cfg.topology_aware, spec=sp, error_feedback=ef)
-        out.append(r.astype(g.dtype))
-        new_ef.append(e)
+    with jax.named_scope("ddl.reduce"):
+        for g, sp, ef in zip(leaves, specs, efs):
+            r, e = ddl_reduce_leaf(
+                g, data_axis=data_axis, pod_axis=pod_axis,
+                data_size=data_size, pod_size=pod_size,
+                compress_dcn=cfg.compress_dcn,
+                topology_aware=cfg.topology_aware, spec=sp,
+                error_feedback=ef)
+            out.append(r.astype(g.dtype))
+            new_ef.append(e)
     ef_out = new_ef if error_feedback is not None else None
     return compat.tree.unflatten(treedef, out), ef_out
 

@@ -253,10 +253,11 @@ def make_grad_reduce_hook(cfg: DDLConfig, *, data_axis: str = "data",
         return tree, None
 
     def bwd(_, ct):
-        red = reduce_tree_bucketed(
-            ct, cfg, data_axis=data_axis, pod_axis=pod_axis,
-            data_size=data_size, pod_size=pod_size, keep=keep,
-            param_specs=param_specs)
+        with jax.named_scope("ddl.reduce"):
+            red = reduce_tree_bucketed(
+                ct, cfg, data_axis=data_axis, pod_axis=pod_axis,
+                data_size=data_size, pod_size=pod_size, keep=keep,
+                param_specs=param_specs)
         return (compat.to_memory_kind(red, sink),)
 
     hook.defvjp(fwd, bwd)

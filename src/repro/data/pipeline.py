@@ -12,6 +12,8 @@ from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
+from repro.obs import span
+
 
 @dataclass
 class DataState:
@@ -100,8 +102,10 @@ class DataLoader:
         return self.state.epoch * 1_000_000 + self.state.step_in_epoch
 
     def _fetch(self):
-        return self.source.batch(self.global_step, self.shard, self.num_shards,
-                                 self.batch_per_shard, self.seq_len)
+        with span("data.fetch"):
+            return self.source.batch(self.global_step, self.shard,
+                                     self.num_shards, self.batch_per_shard,
+                                     self.seq_len)
 
     def __next__(self) -> Dict[str, np.ndarray]:
         batch = self._next if self._next is not None else self._fetch()

@@ -24,7 +24,7 @@ from repro.launch.cache import configure_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import kvquant
 from repro.models.model import Model
-from repro.obs import (configure, export_chrome_trace, get_obs,
+from repro.obs import (configure, get_obs, profiler_trace,
                        write_obs_report)
 from repro.serve import (ServeEngine, decode_step_batch,
                          static_batch_from_requests, synth_requests)
@@ -102,8 +102,9 @@ def main(argv=None):
                    help="stream span events to this JSONL file as they "
                         "are recorded")
     p.add_argument("--trace", default="",
-                   help="write a Chrome trace_event JSON (chrome://tracing "
-                        "/ Perfetto) at exit")
+                   help="run the engine under the JAX profiler, writing its "
+                        "trace (host spans and device ops on one clock) "
+                        "under this directory")
     p.add_argument("--obs-report", default="",
                    help="write the overlap/swap obs report JSON at exit")
     p.add_argument("--profile", default="",
@@ -157,7 +158,8 @@ def main(argv=None):
                       prefill_chunk=args.prefill_chunk,
                       temperature=args.temperature, top_k=args.top_k,
                       kv_dtype=args.kv_dtype, obs=obs)
-    results = eng.run(reqs)
+    with profiler_trace(args.trace):
+        results = eng.run(reqs)
     m = eng.metrics()
     returned = int(m["pool_fetched_pages"] + m["pool_prefetched_pages"])
     print(f"served {len(results)} requests | decode {m['decode_tok_s']:.1f} "
@@ -170,8 +172,7 @@ def main(argv=None):
     print("generated token ids (first request):",
           np.asarray(results[reqs[0].rid])[:16])
     if args.trace:
-        export_chrome_trace(obs.ring.events(), args.trace)
-        print(f"chrome trace: {args.trace}")
+        print(f"profiler trace: {args.trace}")
     if args.obs_report:
         write_obs_report(args.obs_report, obs=eng.obs)
         print(f"obs report: {args.obs_report}")

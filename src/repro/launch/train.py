@@ -16,8 +16,8 @@ from repro.config.base import (DDLConfig, LMSConfig, MeshSpec, ShapeConfig,
                                TrainConfig)
 from repro.configs import get_config, get_smoke_config
 from repro.launch.cache import configure_compile_cache
-from repro.obs import (TelemetryLoop, configure, export_chrome_trace,
-                       get_obs, write_obs_report)
+from repro.obs import (TelemetryLoop, configure, get_obs, profiler_trace,
+                       write_obs_report)
 from repro.runtime import (FaultEvent, FaultInjector, FaultPlan,
                            RestartPolicy, Supervisor)
 from repro.train.trainer import Trainer
@@ -61,8 +61,9 @@ def main(argv=None):
                    help="stream span events to this JSONL file as they "
                         "are recorded")
     p.add_argument("--trace", default="",
-                   help="write a Chrome trace_event JSON (chrome://tracing "
-                        "/ Perfetto) at exit")
+                   help="run under the JAX profiler, writing its trace "
+                        "(host spans and device ops on one clock) under "
+                        "this directory")
     p.add_argument("--obs-report", default="",
                    help="write the overlap/swap obs report JSON at exit")
     p.add_argument("--profile", default="",
@@ -127,27 +128,31 @@ def main(argv=None):
         injector = FaultInjector(FaultPlan.sample(
             args.fault_seed, sites=("trainer.step", "ckpt.commit")))
 
-    if args.supervise:
-        sup = Supervisor(tcfg,
-                         heartbeat_dir=args.heartbeat_dir or None,
-                         policy=RestartPolicy(max_restarts=args.max_restarts,
-                                              backoff_base=0.01,
-                                              max_delay=1.0),
-                         injector=injector, obs=obs, telemetry=telemetry)
-        res = sup.run(steps=args.steps, on_step=log)
-        state, hist = res.state, res.hist
-        for note in res.notes:
-            print(f"reshard: {note}")
-        if res.restarts:
-            print(f"recovered from {res.restarts} failure(s) "
-                  f"in {res.attempts} attempts")
-    else:
-        trainer = Trainer(tcfg, heartbeat_dir=args.heartbeat_dir or None,
-                          injector=injector, obs=obs, telemetry=telemetry,
-                          profile=args.profile or None)
-        if trainer.plan is not None and trainer.plan.calibrated:
-            print(trainer.plan.summary())
-        state, hist = trainer.train(steps=args.steps, on_step=log)
+    with profiler_trace(args.trace):
+        if args.supervise:
+            sup = Supervisor(tcfg,
+                             heartbeat_dir=args.heartbeat_dir or None,
+                             policy=RestartPolicy(
+                                 max_restarts=args.max_restarts,
+                                 backoff_base=0.01, max_delay=1.0),
+                             injector=injector, obs=obs, telemetry=telemetry)
+            res = sup.run(steps=args.steps, on_step=log)
+            state, hist = res.state, res.hist
+            for note in res.notes:
+                print(f"reshard: {note}")
+            if res.restarts:
+                print(f"recovered from {res.restarts} failure(s) "
+                      f"in {res.attempts} attempts")
+        else:
+            trainer = Trainer(tcfg, heartbeat_dir=args.heartbeat_dir or None,
+                              injector=injector, obs=obs,
+                              telemetry=telemetry,
+                              profile=args.profile or None)
+            if trainer.plan is not None and trainer.plan.calibrated:
+                print(trainer.plan.summary())
+            state, hist = trainer.train(steps=args.steps, on_step=log)
+    if args.trace:
+        print(f"profiler trace: {args.trace}")
     if args.log:
         with open(args.log, "w") as f:
             json.dump(hist, f, indent=1)
@@ -155,9 +160,6 @@ def main(argv=None):
         for a in telemetry.alerts:
             print(f"telemetry alert: {a}")
     print(f"final loss: {hist[-1]['loss']:.4f} (from {hist[0]['loss']:.4f})")
-    if args.trace:
-        export_chrome_trace(obs.ring.events(), args.trace)
-        print(f"chrome trace: {args.trace}")
     if args.obs_report:
         write_obs_report(args.obs_report, obs=obs)
         print(f"obs report: {args.obs_report}")

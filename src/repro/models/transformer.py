@@ -233,11 +233,15 @@ def apply_decoder(cfg, params, x, ctx, *, policy=None, no_remat=False,
             stack = params[f"stack{gi}"]
             hook = (grad_hooks or {}).get(f"stack{gi}")
 
+            # the scans' device ops carry train.layers: the forward's as
+            # jvp(train.layers), the backward's (remat recompute included)
+            # as transpose(jvp(train.layers))
             if stream is not None and not unroll:
-                x, aux = _scan_streamed(cfg, stack, (x, aux), ctx, pattern,
-                                        n_iter, policy=policy,
-                                        no_remat=no_remat, stream=stream,
-                                        grad_hook=hook)
+                with jax.named_scope("train.layers"):
+                    x, aux = _scan_streamed(cfg, stack, (x, aux), ctx,
+                                            pattern, n_iter, policy=policy,
+                                            no_remat=no_remat, stream=stream,
+                                            grad_hook=hook)
                 continue
 
             def body(carry, lp, _pattern=pattern, _hook=hook):
@@ -251,8 +255,9 @@ def apply_decoder(cfg, params, x, ctx, *, policy=None, no_remat=False,
 
             if not no_remat:
                 body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-            (x, aux), _ = jax.lax.scan(body, (x, aux), stack,
-                                       unroll=n_iter if unroll else 1)
+            with jax.named_scope("train.layers"):
+                (x, aux), _ = jax.lax.scan(body, (x, aux), stack,
+                                           unroll=n_iter if unroll else 1)
         else:
             _, rem = entry
             for i, k in enumerate(rem):

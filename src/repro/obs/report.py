@@ -1,7 +1,6 @@
-"""Timeline analysis + exporters: the swap/compute overlap report
-(``obs_report.json``, consumed by Planner v2 alongside
-``analysis_report.json``) and the Chrome-trace (`trace_event` format)
-exporter for chrome://tracing / Perfetto (DESIGN.md §12).
+"""Timeline analysis: the swap/compute overlap report (``obs_report.json``,
+consumed by Planner v2 alongside ``analysis_report.json``; DESIGN.md §12).
+The timeline itself is the profiler's: spans are trace annotations there.
 
 Overlap definition: ``overlap_frac`` is the fraction of total SWAP span
 time that lies inside the union of COMPUTE span intervals — exactly the
@@ -22,12 +21,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.obs.trace import Obs, SpanEvent, get_obs
 
-# site -> timeline category (Perfetto track). Order matters: first match.
+# site -> overlap-report category. Order matters: first match.
 COMPUTE_SITES = ("engine.tick", "engine.prefill", "train.step")
 SWAP_PREFIXES = ("lms.swap", "pool.")
 COLLECTIVE_PREFIXES = ("ddl.",)
-
-CATEGORIES = ("compute", "swap", "collective", "other")
 
 
 def categorize(site: str) -> str:
@@ -173,39 +170,3 @@ def load_obs_report(path: str) -> dict:
     from repro.core.lms.costmodel import validate_obs_report
     with open(path) as f:
         return validate_obs_report(json.load(f))
-
-
-# ---------------------------------------------------------------------------
-# Chrome trace_event export
-
-_TIDS = {c: i + 1 for i, c in enumerate(CATEGORIES)}
-
-
-def export_chrome_trace(events: Sequence[SpanEvent], path: str) -> dict:
-    """Write the event set as Chrome `trace_event` JSON. Spans become "X"
-    (complete) events and instants "i" events, each on a per-category
-    track (compute / swap / collective / other) via its tid; "M" metadata
-    events name the tracks so Perfetto renders them distinctly.
-
-    Timestamps are microseconds relative to the earliest event (monotonic
-    origin is arbitrary; only deltas matter on a timeline)."""
-    base = min((e.t0 for e in events), default=0.0)
-    trace_events: List[dict] = [{
-        "name": "process_name", "ph": "M", "pid": 0,
-        "args": {"name": "repro"}}]
-    for cat, tid in _TIDS.items():
-        trace_events.append({"name": "thread_name", "ph": "M", "pid": 0,
-                             "tid": tid, "args": {"name": cat}})
-    for e in events:
-        cat = categorize(e.site)
-        common = {"name": e.site, "cat": f"{cat},{e.kind}", "pid": 0,
-                  "tid": _TIDS[cat], "ts": (e.t0 - base) * 1e6,
-                  "args": dict(e.attrs, depth=e.depth)}
-        if e.kind == "span":
-            trace_events.append({**common, "ph": "X", "dur": e.dur * 1e6})
-        else:
-            trace_events.append({**common, "ph": "i", "s": "t"})
-    doc = {"traceEvents": trace_events, "displayTimeUnit": "ms"}
-    with open(path, "w") as f:
-        json.dump(doc, f, default=str)
-    return doc

@@ -2,12 +2,15 @@
 
 ``span(site, **attrs)`` is a context manager timing a host-side region with
 ``time.monotonic()``; on exit it emits a structured event into a bounded
-ring (and an optional JSONL sink). ``instant(site)`` emits a zero-duration
-point event. ``trace_event(site)`` is the variant for code that runs at JIT
-*trace* time (the LMS swap stream helpers, the DDL bucket builder): it fires
-once per trace, not once per execution, so the report treats its events as
-plan-shaped byte accounting and keeps them OUT of the wall-clock overlap
-math (kind="trace").
+ring (and an optional JSONL sink). Once jax is imported, a span also enters
+``jax.profiler.TraceAnnotation(site)`` for its extent, so a profiler trace
+holds the span as a host event of the same name, on the clock of the device
+ops it dispatched (with the profiler off that costs one TraceMe check).
+``instant(site)`` emits a zero-duration point event. ``trace_event(site)``
+is the variant for code that runs at JIT *trace* time (the LMS swap stream
+helpers, the DDL bucket builder): it fires once per trace, not once per
+execution, so the report treats its events as plan-shaped byte accounting
+and keeps them OUT of the wall-clock overlap math (kind="trace").
 
 An ``Obs`` bundles a ``MetricsRegistry`` with a ring. The module-level
 default (``get_obs()``/``configure()``) is what free-standing helpers
@@ -24,6 +27,7 @@ from __future__ import annotations
 
 import contextlib
 import json
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -102,6 +106,33 @@ class TraceRing:
             return len(self._events)
 
 
+_annotation = None
+
+
+def _profiler_annotation(site: str):
+    """``jax.profiler.TraceAnnotation(site)`` where jax is already imported
+    (resolved once), else a no-op: importing obs never imports jax."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(site)
+
+
+def profiler_trace(log_dir: str):
+    """Run the block under the JAX profiler, writing its trace under
+    `log_dir` (host spans and device ops on one clock); '' traces nothing.
+    The Python function tracer stays off: it would slow every host call."""
+    if not log_dir:
+        return contextlib.nullcontext()
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(log_dir, profiler_options=opts)
+
+
 class Obs:
     """A metrics registry + an event ring, the unit every instrumented
     component holds. ``Obs()`` = private registry, shared global ring."""
@@ -118,7 +149,8 @@ class Obs:
     @contextlib.contextmanager
     def span(self, site: str, **attrs) -> Iterator[SpanEvent]:
         """Time a host-side region; the event is recorded on exit (even on
-        exception) with the nesting depth at entry."""
+        exception) with the nesting depth at entry. The profiler sees the
+        site alone; the attrs stay in the ring."""
         check_site(site)
         depth = self._depth()
         self._local.depth = depth + 1
@@ -126,7 +158,8 @@ class Obs:
         ev = SpanEvent(site, t0, 0.0, "span", depth,
                        threading.get_ident(), dict(attrs))
         try:
-            yield ev
+            with _profiler_annotation(site):
+                yield ev
         finally:
             ev.dur = time.monotonic() - t0
             self._local.depth = depth

@@ -572,11 +572,12 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
             # resident path, applied per layer slice with swap-in/swap-out
             gnorm = global_norm(grads)
             scale = clip_scale(gnorm, tcfg.grad_clip)
-            new_params, new_opt = _streamed_opt_update(
-                tcfg.optimizer, grads, opt_state, params, cfg=cfg, lr=lr,
-                beta1=tcfg.beta1, beta2=tcfg.beta2,
-                weight_decay=tcfg.weight_decay, clip_scale=scale,
-                schedule=opt_stream, params_host=params_host)
+            with jax.named_scope("lms.opt_sweep"):
+                new_params, new_opt = _streamed_opt_update(
+                    tcfg.optimizer, grads, opt_state, params, cfg=cfg, lr=lr,
+                    beta1=tcfg.beta1, beta2=tcfg.beta2,
+                    weight_decay=tcfg.weight_decay, clip_scale=scale,
+                    schedule=opt_stream, params_host=params_host)
         else:
             grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
             new_params, new_opt = opt_update(

@@ -130,24 +130,25 @@ class Trainer:
         cfg = self.tcfg.model
         b, s = self.tcfg.shape.global_batch, self.tcfg.shape.seq_len
         rng = np.random.default_rng(self.loader.global_step)
+        # the host makes the rows; the train.put span covers their
+        # conversion and transfer to the batch's shardings
+        bf16 = ()
         if cfg.family == "vlm":
             raw = make_vlm_batch(rng, b, s, cfg.d_model, cfg.vocab_size)
             raw["embeds"] = raw["embeds"].astype(np.float32)
-            batch = {"embeds": jnp.asarray(raw["embeds"], jnp.bfloat16),
-                     "positions3": jnp.asarray(raw["positions3"]),
-                     "labels": jnp.asarray(raw["labels"])}
+            bf16 = ("embeds",)
             self.loader.state.step_in_epoch += 1
         elif cfg.family == "audio":
             raw = make_audio_batch(rng, b, s, cfg.encoder_seq, cfg.d_model,
                                    cfg.vocab_size)
-            batch = {"enc_embeds": jnp.asarray(raw["enc_embeds"], jnp.bfloat16),
-                     "tokens": jnp.asarray(raw["tokens"]),
-                     "labels": jnp.asarray(raw["labels"])}
+            bf16 = ("enc_embeds",)
             self.loader.state.step_in_epoch += 1
         else:
             raw = next(self.loader)
-            batch = {k: jnp.asarray(v) for k, v in raw.items()}
-        return jax.device_put(batch, self.batch_sh)
+        with self.obs.span("train.put"):
+            batch = {k: jnp.asarray(v, jnp.bfloat16 if k in bf16 else None)
+                     for k, v in raw.items()}
+            return jax.device_put(batch, self.batch_sh)
 
     # ---- loop ----------------------------------------------------------
     def train(self, steps: Optional[int] = None,
@@ -193,19 +194,22 @@ class Trainer:
             flush_now = (i + 1) % log_every == 0 or i + 1 == steps
             with self.obs.span("train.step", step=i + 1):
                 batch = self._make_batch()
-                state, metrics = self.step_fn(state, batch)
+                with self.obs.span("train.dispatch"):
+                    state, metrics = self.step_fn(state, batch)
                 if flush_now:
                     # sync inside the timed span so a flush step's dt (and
                     # span) covers the compute it absorbs; non-flush steps
                     # record dispatch-side timing only
-                    jax.block_until_ready(metrics)
+                    with self.obs.span("train.sync"):
+                        jax.block_until_ready(metrics)
             dt = self.timer.stop()
             step_hist.observe(dt)
             pending.append((i + 1, metrics, dt))
             if self.hb:
                 self._beat(i + 1, dt)
             if flush_now:
-                _flush()
+                with self.obs.span("train.flush"):
+                    _flush()
             every = self.tcfg.checkpoint_every
             if self.ckpt is not None and ((i + 1) % every == 0
                                           or i + 1 == steps):

@@ -145,6 +145,16 @@ def test_rl007_obs_site_names():
                   waived=False) == []
 
 
+def test_rl007_named_scope_sites():
+    # a named scope is a device-side site: registered and dotted, or flagged
+    assert _codes('with jax.named_scope("train.layers"):\n    pass\n') == []
+    assert _codes('with jax.named_scope("lms.opt_sweep"):\n    pass\n') == []
+    assert _codes('with jax.named_scope("layers"):\n    pass\n') == ["RL007"]
+    assert _codes('with jax.named_scope("model.layers"):\n    pass\n') \
+        == ["RL007"]
+    assert _codes("with jax.named_scope(name):\n    pass\n") == []
+
+
 def test_rl004_tracer_host_pull_scoped_to_hot_paths():
     src = "def _tick(self):\n    rows = np.asarray(logits)\n"
     assert _codes(src, path="serve/engine.py") == ["RL004"]

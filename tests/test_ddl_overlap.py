@@ -243,3 +243,43 @@ print("ZERO1-2D-OK")
 
 def test_zero1_overlap_parity_2d():
     assert "ZERO1-2D-OK" in run_py(ZERO1_PARITY_2D, devices=8)
+
+
+# ---------------------------------------------------------------------------
+# the reductions carry the ddl.reduce name scope: the per-layer hook's
+# inside the backward layer scan, the post-hoc tree pass outside it
+# ---------------------------------------------------------------------------
+
+REDUCE_SCOPE = """
+import re
+import jax, jax.numpy as jnp
+from repro.configs import get_smoke_config
+from repro.models import Model
+from repro.config.base import TrainConfig, ShapeConfig, MeshSpec, DDLConfig
+from repro.train.steps import build_train_step, init_train_state
+from repro.launch.mesh import make_mesh
+mesh_spec = MeshSpec((2,), ("data",))
+cfg = get_smoke_config("olmo-1b")
+model = Model(cfg, attn_impl="naive")
+tcfg = TrainConfig(model=cfg, shape=ShapeConfig("smoke", "train", 16, 4),
+                   mesh=mesh_spec, ddl=DDLConfig(mode="allreduce"))
+state = jax.eval_shape(lambda: init_train_state(model, tcfg,
+                                                jax.random.key(0)))
+batch = {k: jax.ShapeDtypeStruct((4, 16), jnp.int32)
+         for k in ("tokens", "labels")}
+for overlap in (True, False):
+    fn, _, _ = build_train_step(model, tcfg, make_mesh(mesh_spec),
+                                donate=False, overlap_grads=overlap)
+    text = fn.lower(state, batch).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*ddl[.]reduce/[^"]*)"', text))
+    in_scan = {n for n in names if "transpose(jvp(train.layers))/" in n}
+    assert any("reduce_scatter" in n for n in names - in_scan), overlap
+    assert bool(in_scan) == overlap, (overlap, sorted(in_scan)[:3])
+    if overlap:
+        assert any("reduce_scatter" in n for n in in_scan)
+print("REDUCE-SCOPE-OK")
+"""
+
+
+def test_reductions_carry_the_ddl_reduce_scope():
+    assert "REDUCE-SCOPE-OK" in run_py(REDUCE_SCOPE, devices=2)

@@ -1,7 +1,12 @@
 """Observability layer (DESIGN.md §12): metrics registry semantics, span
-nesting + ring bounds, JSONL sink, Chrome-trace export, overlap report on
-synthetic spans, spike detection, and the trainer's log_every flush."""
+nesting + ring bounds, JSONL sink, spans as profiler host events, overlap
+report on synthetic spans, spike detection, and the trainer's spans and
+log_every flush."""
+import glob
 import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -9,8 +14,9 @@ import pytest
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Obs,
                        SpanEvent, SpikeDetector, TelemetryAlert,
                        TelemetryLoop, TraceRing, build_obs_report,
-                       categorize, check_site, export_chrome_trace,
-                       overlap_report)
+                       categorize, check_site, overlap_report)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _iso_obs(maxlen=8192):
@@ -210,34 +216,65 @@ def test_build_obs_report_shape():
 
 
 # ---------------------------------------------------------------------------
-# chrome trace export
+# spans on the profiler's clock
 
 
-def test_chrome_trace_well_formed(tmp_path):
-    events = [
-        _ev("engine.tick", 1.0, 0.5),
-        _ev("pool.prefetch", 1.1, 0.2, cls="kvcache"),
-        _ev("ddl.bucket", 1.2, 0.0, kind="trace", buckets=3),
-        _ev("sup.restart", 1.3, 0.0, kind="instant"),
-    ]
-    path = str(tmp_path / "trace.json")
-    doc = export_chrome_trace(events, path)
-    assert json.load(open(path)) == json.loads(json.dumps(doc))
-    tes = doc["traceEvents"]
-    metas = [e for e in tes if e["ph"] == "M"]
-    names = {e["args"]["name"] for e in metas}
-    assert {"repro", "compute", "swap", "collective", "other"} <= names
-    xs = [e for e in tes if e["ph"] == "X"]
-    assert {e["name"] for e in xs} == {"engine.tick", "pool.prefetch"}
-    # per-category tracks: compute and swap land on distinct tids
-    by_name = {e["name"]: e for e in tes if e["ph"] in ("X", "i")}
-    assert by_name["engine.tick"]["tid"] != by_name["pool.prefetch"]["tid"]
-    # timestamps are relative microseconds from the earliest event
-    assert by_name["engine.tick"]["ts"] == pytest.approx(0.0)
-    assert by_name["pool.prefetch"]["ts"] == pytest.approx(0.1e6)
-    assert by_name["engine.tick"]["dur"] == pytest.approx(0.5e6)
-    instants = [e for e in tes if e["ph"] == "i"]
-    assert {e["name"] for e in instants} == {"ddl.bucket", "sup.restart"}
+def _host_events(log_dir):
+    """{name: count} of the host events in the profiler trace under
+    log_dir."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(paths) == 1, paths
+    counts = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    counts[e.name] = counts.get(e.name, 0) + 1
+    return counts
+
+
+def test_span_is_a_host_event_of_the_profiler_trace(tmp_path):
+    import jax
+    obs = _iso_obs()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("test.one_clock", step=3):
+            jax.block_until_ready(jax.numpy.ones(4) + 1)
+    # the event is named by the site alone; the attrs stay in the ring
+    assert _host_events(str(tmp_path)).get("test.one_clock") == 1
+    assert [(e.site, e.attrs) for e in obs.ring.events()] == [
+        ("test.one_clock", {"step": 3})]
+
+
+def test_obs_import_and_spans_do_not_import_jax():
+    code = ("import sys\n"
+            "from repro.obs import span\n"
+            "with span('test.no_jax'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'obs imported jax'\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env=dict(os.environ,
+                                  PYTHONPATH=os.path.join(REPO, "src")))
+    assert out.returncode == 0, out.stderr
+
+
+def test_launch_train_trace_writes_a_profiler_trace(tmp_path):
+    trace_dir = str(tmp_path / "trace")
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch", "olmo-1b",
+         "--smoke", "--steps", "2", "--batch", "2", "--seq", "16",
+         "--ckpt-every", "0", "--trace", trace_dir],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 PYTHONPATH=os.path.join(REPO, "src")))
+    assert out.returncode == 0, out.stderr[-2000:]
+    host = _host_events(trace_dir)
+    assert host.get("train.step") == 2
+    for site in ("data.fetch", "train.put", "train.dispatch", "train.sync",
+                 "train.flush"):
+        assert host.get(site, 0) >= 2, (site, host.get(site))
 
 
 # ---------------------------------------------------------------------------
@@ -320,6 +357,27 @@ def test_trainer_log_every_flush_order(tmp_path):
     assert len(spans) == 5
     assert len(obs.registry.series("train.history")) == 5
     assert obs.registry.histogram("train.step_s").count == 5
+
+
+def test_trainer_loop_spans(tmp_path):
+    from repro.obs import get_obs, reset
+    from repro.train.trainer import Trainer
+    reset()
+    obs = _iso_obs()
+    tr = Trainer(_tcfg(tmp_path, steps=3, log_every=2), attn_impl="naive",
+                 obs=obs)
+    tr.train()
+    # one step: put and dispatch inside train.step, the sync on a flush
+    # step only, the flush after the step's span
+    assert [(e.site, e.depth) for e in obs.ring.events()] == [
+        ("train.put", 1), ("train.dispatch", 1), ("train.step", 0),
+        ("train.put", 1), ("train.dispatch", 1), ("train.sync", 1),
+        ("train.step", 0), ("train.flush", 0),
+        ("train.put", 1), ("train.dispatch", 1), ("train.sync", 1),
+        ("train.step", 0), ("train.flush", 0)]
+    # the loader makes its rows under data.fetch (one ahead: a prefetch)
+    fetches = [e for e in get_obs().ring.events() if e.site == "data.fetch"]
+    assert len(fetches) == 4
 
 
 class _SpikeAt:

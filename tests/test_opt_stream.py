@@ -330,3 +330,37 @@ def test_step_metrics_carry_real_model_aux(microbatches):
     np.testing.assert_allclose(float(m["loss"]),
                                float(m["ce"]) + 0.01 * float(m["aux"]),
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("params", ["device", "host"],
+                         ids=["resident_scan", "streamed_scan"])
+def test_step_phases_carry_their_named_scopes(params):
+    """The benchmark splits the step's device time by JAX name scope: the
+    forward layer scan, the backward one (remat recompute included) and the
+    optimizer sweep must each carry theirs in the compiled step's op_name
+    metadata, on both decoder scan paths."""
+    import re
+    from repro.train.steps import build_train_step, init_train_state
+    cfg = get_smoke_config("olmo-1b")
+    model = Model(cfg, attn_impl="naive")
+    mesh_spec = MeshSpec((1, 1), ("data", "model"))
+    tcfg = TrainConfig(model=cfg, shape=ShapeConfig("smoke", "train", 16, 2),
+                       mesh=mesh_spec, ddl=DDLConfig(mode="allreduce"))
+    plan = _plan(cfg, {"params": params, "grads": "device",
+                       "optimizer": "host", "kvcache": "device"})
+    fn, _, _ = build_train_step(model, tcfg, make_mesh(mesh_spec), plan=plan,
+                                donate=False)
+    state = jax.eval_shape(
+        lambda: init_train_state(model, tcfg, jax.random.key(0)))
+    batch = jax.eval_shape(lambda: _batch(cfg))
+    text = fn.lower(state, batch).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    fwd = [n for n in names if "/jvp(train.layers)/" in n]
+    bwd = [n for n in names if "/transpose(jvp(train.layers))/" in n]
+    assert fwd and any("dot_general" in n for n in fwd)
+    assert any("/rematted_computation/" in n and "dot_general" in n
+               for n in bwd)
+    assert any("/lms.opt_sweep/" in n for n in names)
+    # the sweep is its own phase, outside the layer scans
+    assert not any("train.layers" in n and "lms.opt_sweep" in n
+                   for n in names)

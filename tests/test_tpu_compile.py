@@ -165,9 +165,16 @@ def test_train_step_with_host_optimizer_compiles(one_chip, monkeypatch):
     specs, _ = model.input_specs(tcfg.shape, mesh)
     batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=batch_sh[k])
              for k, v in specs.items()}
-    mem = step.lower(state, batch).compile().memory_analysis()
+    compiled = step.lower(state, batch).compile()
+    mem = compiled.memory_analysis()
     assert mem.host_argument_size_in_bytes > 0
     assert mem.host_alias_size_in_bytes == mem.host_argument_size_in_bytes
+    # the TPU compiler keeps the step's phase scopes in the op metadata
+    # that the profiler reports for each device op
+    text = compiled.as_text()
+    for scope in ("/jvp(train.layers)/", "/transpose(jvp(train.layers))/",
+                  "/lms.opt_sweep/"):
+        assert scope in text, scope
 
 
 def test_overlapped_ddl_step_compiles_without_collective_fusions(topo):
