@@ -46,10 +46,17 @@ STREAM_CLASSES = ("params", "kvcache", "optimizer", "grads")
 # The streamed optimizer sweep updates large UNSCANNED remainder leaves
 # (embeddings, LM head) in up to this many row blocks (see `rest_chunks`),
 # streamed in/out per block — bounding the remainder's optimizer working
-# set to ~2 blocks of state the same way the layer sweep bounds the decoder
-# stacks to ~2 layers. Shared with the executor (train/steps.py imports
-# `rest_chunks`) so pricing and execution cannot drift.
+# set to a few blocks of state the same way the layer sweep bounds the
+# decoder stacks to a few layers. Shared with the executor (train/steps.py
+# imports `rest_chunks`) so pricing and execution cannot drift.
 OPT_REST_CHUNKS = 16
+
+# Units of optimizer state the streamed sweep holds in HBM at once. It is
+# a software pipeline (`steps._pipelined_sweep`): a unit swaps in and is
+# updated while the previous unit's results go back out, so two units are
+# in flight. A unit is one scan iteration of a decoder stack, or one row
+# block of a chunked remainder leaf.
+OPT_SWEEP_UNITS = 2
 
 
 def rest_chunks(shape, size: int) -> int:
@@ -64,26 +71,44 @@ def rest_chunks(shape, size: int) -> int:
     return min(int(shape[0]), OPT_REST_CHUNKS)
 
 
-def rest_leaf_shapes(cfg: ModelConfig) -> List[Tuple[int, ...]]:
-    """Shapes of the leaves the streamed optimizer sweep updates outside
-    the decoder scan stacks (embeddings, LM head, norms, unrolled tail
-    layers, encoder), from the model's own param defs."""
+def _sweep_leaf_shapes(cfg: ModelConfig):
+    """-> ({decoder scan stack name: its leaf shapes}, [shapes of every
+    other leaf]) from the model's own param defs: the sets the streamed
+    optimizer sweep updates layer by layer and row block by row block."""
     from repro.models.layers import ParamDef
     from repro.models.model import Model
 
-    defs = Model(cfg).param_defs()
-    defs["decoder"] = {k: v for k, v in defs["decoder"].items()
-                       if not k.startswith("stack")}
-    out: List[Tuple[int, ...]] = []
-
-    def walk(node):
+    def shapes(node):
         if isinstance(node, ParamDef):
-            out.append(tuple(node.shape))
-        elif isinstance(node, dict):
-            for v in node.values():
-                walk(v)
-    walk(defs)
-    return out
+            return [tuple(node.shape)]
+        if isinstance(node, dict):
+            return [s for v in node.values() for s in shapes(v)]
+        return []
+
+    defs = Model(cfg).param_defs()
+    dec = defs.pop("decoder")
+    stacks = {k: shapes(v) for k, v in dec.items() if k.startswith("stack")}
+    rest = shapes(defs) + shapes({k: v for k, v in dec.items()
+                                  if not k.startswith("stack")})
+    return stacks, rest
+
+
+def opt_sweep_elements(cfg: ModelConfig) -> int:
+    """Parameters whose optimizer state the streamed sweep holds in HBM at
+    once, as the executor runs it: OPT_SWEEP_UNITS units of the largest
+    decoder stack (one scan iteration each; the stacks sweep one after
+    another), plus OPT_SWEEP_UNITS row blocks of each chunked remainder
+    leaf and the whole of each leaf updated in one shot."""
+    stacks, rest = _sweep_leaf_shapes(cfg)
+    unit = max((sum(math.prod(s[1:]) for s in v) for v in stacks.values()),
+               default=0)
+    n = OPT_SWEEP_UNITS * unit
+    for shp in rest:
+        size = math.prod(shp)
+        c = rest_chunks(shp, size)
+        n += (size if c <= 1
+              else OPT_SWEEP_UNITS * (shp[0] // c) * (size // shp[0]))
+    return n
 
 # Optimizer pricing per known optimizer: fp32 m+v+master (adamw) vs fp32
 # momentum (sgdm) state bytes per parameter, and the per-step HBM
@@ -910,20 +935,12 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
                 notes.append("optimizer shard host-resident (zero1: flat "
                              "1/|data| state, placement-only transfer)")
             else:
-                # resident during the sweep: 2 double-buffered layer slices
-                # PLUS the unscanned remainder (embeddings, lm head, norms,
-                # encoder), whose large leaves update in up to
-                # OPT_REST_CHUNKS streamed row blocks (2 in flight). Priced
-                # leaf by leaf with the executor's own `rest_chunks` rule,
-                # so a leaf the executor updates in one shot is charged at
-                # its full state
-                rest_dev = 0
-                for shp in rest_leaf_shapes(cfg):
-                    n = math.prod(shp)
-                    c = rest_chunks(shp, n)
-                    live = n if c <= 1 else 2 * (shp[0] // c) * (n // shp[0])
-                    rest_dev += opt_mult * live // tp
-                opt_dev = 2 * opt_host // max(L, 1) + rest_dev
+                # resident during the sweep: the units the pipelined sweep
+                # holds at once (OPT_SWEEP_UNITS) of the decoder stacks and
+                # of each chunked remainder leaf, priced from the executor's
+                # own unit sizes and `rest_chunks` rule; a leaf the executor
+                # updates in one shot is charged at its full state
+                opt_dev = opt_mult * opt_sweep_elements(cfg) // tp
                 notes.append("optimizer state host-resident, streamed per "
                              "layer (ZeRO-Offload style sweep)")
             residency["optimizer"] = "host"
@@ -1042,8 +1059,10 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
             worst = max(streamed, key=lambda c: per_layer[c] / cost.bw(c))
             want = cost.tune_prefetch_depth(L, per_layer[worst], layer_time,
                                             cls_name=worst)
+            # the optimizer sweep moves one unit at a time at any depth:
+            # its working set does not grow with the depth
             inc = {"params": 2 * params_dev // max(L, 1),
-                   "optimizer": opt_mult * n_params // tp // max(L, 1),
+                   "optimizer": 0,
                    "grads": grads_dev // max(L, 1),
                    "kvcache": 0}
             extra = sum(inc.get(c, 0) for c in streamed)

@@ -27,9 +27,10 @@ Host residency is EXECUTED for every class the plan's SwapSchedule streams
 (DESIGN.md §6): params/kvcache in the decoder scans (PR 1), the optimizer
 state via the streamed per-layer sweep (`_streamed_opt_update` — swap a
 layer's (mu, nu, master) slice in, update with the shared per-slice kernel,
-swap it back), and gradients via the overlapped-backward hooks' host sink
-(each layer's reduced cotangent leaves HBM as it is produced; the optimizer
-sweep reads it back layer by layer).
+swap it back while the next layer's slice comes in), and gradients via the
+overlapped-backward hooks' host sink (each layer's reduced cotangent leaves
+HBM as it is produced; the optimizer sweep reads it back layer by
+layer).
 """
 from __future__ import annotations
 
@@ -59,6 +60,7 @@ from repro.models.model import Model
 from repro.models import kvquant
 from repro.models import paging
 from repro.models.sharding import sharding_env, rules_without, spec as mkspec
+from repro.obs import get_obs
 from repro.optim.adamw import (OPTIMIZERS, AdamState, SGDState,
                                adamw_slice_update, clip_by_global_norm,
                                clip_leaf, clip_scale, global_norm,
@@ -232,29 +234,110 @@ def _map_kernel(kernel, nout: int, *trees):
                  for i in range(nout))
 
 
+def _pipelined_sweep(kind: str, arrs, cls, host, reads, writes, update, n: int,
+                     rows: int, tail: int):
+    """Run `update` over units 0..n-1 of `rows` leading rows of every array
+    in `arrs` (then one unit of the `tail` leftover rows, when `rows` does
+    not divide the leading dim) as a software pipeline, writing each unit
+    back in place -> the new arrays.
+
+    arrs[k] is host-resident iff host[k] (swap class cls[k]); the update
+    reads its unit iff reads[k] and writes it back iff writes[k].
+    `update(ins) -> outs` maps one unit's slices (None where not read) to
+    new slices (None where not written). The arrays ride in the carry and
+    each unit is written back in place (dynamic_update_slice): on TPU the
+    host-resident arrays are never copied or reshaped, only sliced, and no
+    output accumulates in HBM.
+
+    Iteration i swaps unit i in and updates it while unit i-1's results,
+    carried in from the previous iteration, go back out: the write-backs
+    share the host link with the swap-ins (both directions at once)
+    instead of following them, and two units are in HBM at once. XLA
+    orders an array's swap-in before the in-place write-back that follows
+    it, so each array's write-back starts as its own swap-in lands. The
+    carry holds update results, never swapped-in slices: a carried slice,
+    or a slice of an array written earlier in the same iteration, makes
+    the TPU compiler relayout whole host arrays whose layout is not its
+    default one. Unit 0 is swapped in and updated before the loop and the
+    last unit written back after it: no unit moves twice. Trace-time
+    counters: `lms.sweep_units.<kind>` (units swept) and
+    `lms.sweep_units_ahead.<kind>` (units whose swap-in shares the link
+    with the previous unit's write-back: all but the first)."""
+    reg = get_obs().registry
+    units = n + (1 if tail else 0)
+    reg.counter(f"lms.sweep_units.{kind}").inc(units)
+    reg.counter(f"lms.sweep_units_ahead.{kind}").inc(units - 1)
+
+    def write_back(arrs, outs, start):
+        for k, o in enumerate(outs):
+            if o is not None:
+                if host[k]:
+                    o = stream_layer_to_host(o, cls=cls[k])
+                arrs[k] = jax.lax.dynamic_update_slice_in_dim(arrs[k], o,
+                                                              start, 0)
+
+    def run(arrs, last, start, size):
+        """Swap in and update the unit at `start`, writing `last` = (its
+        start, the previous unit's results) back -> (arrays, results)."""
+        ins = [None if not r
+               else stream_layer_to_device(
+                   jax.lax.dynamic_slice_in_dim(a, start, size), cls=c) if h
+               else jax.lax.dynamic_slice_in_dim(a, start, size)
+               for a, c, h, r in zip(arrs, cls, host, reads)]
+        arrs = list(arrs)
+        if last is not None:
+            write_back(arrs, last[1], last[0])
+        return arrs, update(ins)
+
+    # unit 0 runs in a loop of its own, whose one trip XLA cannot see
+    # through the barrier: in the top-level computation the TPU compiler
+    # tiles a one-row slice of a host array whose layout tiles the sliced
+    # axis (starcoder2's [L, 36, 128] query bias) like the array, and then
+    # refuses it; in a conditional branch it relayouts whole host arrays
+    outs = jax.lax.fori_loop(
+        0, jax.lax.optimization_barrier(jnp.int32(1)),
+        lambda i, o: run(arrs, None, i * rows, rows)[1],
+        [jnp.zeros((rows,) + a.shape[1:], a.dtype) if w else None
+         for a, w in zip(arrs, writes)])
+    arrs, outs = jax.lax.fori_loop(
+        1, n, lambda i, c: run(c[0], ((i - 1) * rows, c[1]), i * rows, rows),
+        (list(arrs), outs))
+    last = (n - 1) * rows
+    if tail:
+        arrs, outs = run(arrs, (last, outs), n * rows, tail)
+        last = n * rows
+    arrs = list(arrs)
+    write_back(arrs, outs, last)
+    return arrs
+
+
 def _streamed_opt_update(optimizer: str, grads, opt_state, params, *, cfg,
                          lr, beta1, beta2, weight_decay, clip_scale,
-                         schedule, params_host: bool):
-    """Execute the optimizer update as a per-layer streamed sweep.
+                         params_host: bool, grads_host: bool):
+    """Execute the optimizer update as a streamed sweep over the state.
 
     When the plan's residency places the optimizer state on host, the
     monolithic `opt_update` would pull the FULL fp32 (mu, nu, master) tree
     into HBM — O(params) — exactly what the plan's peak claims not to
-    happen. Instead, a `lax.scan` over each decoder stack group's layer
-    axis swaps one `prefetch_depth`-layer slice of the state (and the
-    layer's gradient, which may itself be host-resident) into HBM, applies
-    the shared per-slice update kernel (`optim/adamw.py`), and swaps the
-    result straight back — double-buffered like the PR-1 param stream, so
-    the copy of slice i+1 overlaps the update of slice i and the optimizer
-    HBM working set is O(params/L). The unscanned remainder (embeddings,
-    norms, unrolled tail layers, encoder) updates in row blocks.
+    happen. Instead each decoder stack group is swept one scan unit (one
+    layer, or one block-pattern group) at a time, and each large
+    unscanned remainder leaf (embeddings, LM head) one row block at a time
+    (`rest_chunks`): the unit's state slice, and its gradient / param
+    slices where those are host-resident (`grads_host`: the stacks' grads
+    only; `params_host`), swap into HBM, the shared per-slice kernel
+    (`optim/adamw.py`) updates them, and the result swaps straight back into
+    the host arrays in place. Each sweep is a software pipeline
+    (`_pipelined_sweep`): the write-back of one unit and the swap-in of the
+    next share the host link and two units are in HBM at once, so the
+    optimizer working set is O(params/L). Small remainder leaves update in
+    one shot.
 
     Numerics: the kernels are the SAME elementwise expressions the resident
     path maps over whole leaves (clip included, via `clip_leaf`), and
     elementwise math is slicing-invariant, so streamed == resident
     byte-for-byte; the swap placements are identity on single-memory-space
     platforms. -> (new_params, new_opt_state)."""
-    from repro.models.transformer import stack_plan, _stream_depth
+    from repro.models.transformer import stack_plan
 
     step = opt_state.step + 1
     if optimizer == "adamw":
@@ -278,20 +361,65 @@ def _streamed_opt_update(optimizer: str, grads, opt_state, params, *, cfg,
     else:
         raise ValueError(f"no streamed sweep for optimizer {optimizer!r}")
     nstate = len(state_trees)
+    width = nstate + 2                    # per leaf: grad, state..., param
+    # the first unit's swap-ins depend on nothing the backward computes, and
+    # the scheduler would otherwise hoist them into the layer scans, where
+    # the fetched unit sits in HBM through the backward's peak: tie them to
+    # the clip scale, which is known once every gradient is
+    clip_scale, state_trees = jax.lax.optimization_barrier(
+        (clip_scale, state_trees))
+
+    def sweep(kind, g, states, p, g_host, n=None, rows=None, tail=0):
+        """Update one set of trees whose leaves share a leading dim, in `n`
+        units of `rows` rows (one shot when n is None) -> (new state
+        trees, new params)."""
+        g_leaves, treedef = compat.tree.flatten(g)
+        s_leaves = [compat.tree.leaves(s) for s in states]
+        p_leaves = compat.tree.leaves(p)
+        arrs = [a for j, gj in enumerate(g_leaves)
+                for a in (gj, *(s[j] for s in s_leaves), p_leaves[j])]
+        cls = ("grads",) + ("optimizer",) * nstate + ("params",)
+        host = (g_host,) + (True,) * nstate + (params_host,)
+        reads = (True,) * (nstate + 1) + (needs_params,)
+        writes = (False,) + (True,) * (nstate + 1)
+        per = len(g_leaves)
+        cls, host, reads, writes = (x * per for x in (cls, host, reads,
+                                                      writes))
+
+        def update(ins):
+            """One unit of every leaf, laid out (grad, state..., param) per
+            leaf -> the new (state..., param) slices in the same layout."""
+            outs = [None] * len(ins)
+            for b in range(0, len(ins), width):
+                g = clip_leaf(ins[b], clip_scale)
+                if needs_params:
+                    m2, p2 = kernel(g, *ins[b + 1:b + 1 + nstate],
+                                    ins[b + nstate + 1])
+                    new = (m2,)
+                else:
+                    new = kernel(g, *ins[b + 1:b + 1 + nstate])
+                    p2 = new[-1].astype(arrs[b + nstate + 1].dtype)
+                outs[b + 1:b + nstate + 2] = (*new, p2)
+            return outs
+
+        if n is None:
+            outs = update([stream_layer_to_device(a, cls=c) if h and r
+                           else a if r else None
+                           for a, c, h, r in zip(arrs, cls, host, reads)])
+            out = [a if o is None else
+                   stream_layer_to_host(o, cls=c) if h else o
+                   for a, o, c, h in zip(arrs, outs, cls, host)]
+        else:
+            out = _pipelined_sweep(kind, arrs, cls, host, reads, writes,
+                                   update, n, rows, tail)
+        return (tuple(treedef.unflatten(out[1 + k::width])
+                      for k in range(nstate)),
+                treedef.unflatten(out[nstate + 1::width]))
 
     g_stacks, g_rest = _split_stack_grads(grads)
     p_stacks, p_rest = _split_stack_grads(params)
     s_splits = [_split_stack_grads(t) for t in state_trees]
     s_stacks, s_rests = [s[0] for s in s_splits], [s[1] for s in s_splits]
-
-    def take(tree, i, n):
-        return compat.tree.map(
-            lambda x: jax.lax.dynamic_slice_in_dim(x, i * n, n), tree)
-
-    def put(tree, new, i, n):
-        return compat.tree.map(
-            lambda x, y: jax.lax.dynamic_update_slice_in_dim(x, y, i * n, 0),
-            tree, new)
 
     new_p_stacks: Dict[str, Any] = {}
     new_s_stacks: list = [{} for _ in range(nstate)]
@@ -299,100 +427,32 @@ def _streamed_opt_update(optimizer: str, grads, opt_state, params, *, cfg,
         if entry[0] != "scan":
             continue
         name = f"stack{gi}"
-        n_iter = entry[2]
-        d = _stream_depth(schedule, n_iter)
-        # static dtypes for the master -> param cast (no data dependency)
-        dts = compat.tree.map(lambda p: p.dtype, p_stacks[name])
-
-        def body(i, carry, _name=name, _d=d, _dts=dts):
-            # the state and param stacks ride in the carry and each slice
-            # is written back in place (dynamic_update_slice): on TPU the
-            # host-resident stacks are never copied or reshaped, only
-            # sliced, and no stacked output accumulates in HBM
-            states, p_out = carry
-            # swap-ins first (state slice i+1's copy overlaps update i);
-            # identity for classes already device-resident
-            s_l = stream_layer_to_device(
-                tuple(take(t, i, _d) for t in states), cls="optimizer")
-            g_l = stream_layer_to_device(take(g_stacks[_name], i, _d),
-                                         cls="grads")
-            g_l = compat.tree.map(lambda g: clip_leaf(g, clip_scale), g_l)
-            if needs_params:
-                p_l = stream_layer_to_device(take(p_out, i, _d),
-                                             cls="params")
-                m2, p2 = _map_kernel(kernel, 2, g_l, s_l[0], p_l)
-                out_state = (m2,)
-            else:
-                m2, v2, mp2 = _map_kernel(kernel, 3, g_l, s_l[0], s_l[1],
-                                          s_l[2])
-                p2 = compat.tree.map(lambda mp, dt: mp.astype(dt), mp2, _dts)
-                out_state = (m2, v2, mp2)
-            # swap the updated slice straight back out
-            out_state = stream_layer_to_host(out_state, cls="optimizer")
-            if params_host:
-                p2 = stream_layer_to_host(p2, cls="params")
-            states = tuple(put(t, o, i, _d) for t, o in zip(states, out_state))
-            return states, put(p_out, p2, i, _d)
-
-        states, new_p_stacks[name] = jax.lax.fori_loop(
-            0, n_iter // d, body,
-            (tuple(s[name] for s in s_stacks), p_stacks[name]))
+        states, new_p_stacks[name] = sweep(
+            "stack", g_stacks[name], [s[name] for s in s_stacks],
+            p_stacks[name], grads_host, entry[2], 1)
         for k in range(nstate):
             new_s_stacks[k][name] = states[k]
 
     # unscanned remainder (embeddings, norms, rem layers, encoder): no layer
     # axis, but its LARGE leaves (embedding / lm-head state is GB-scale on
-    # production vocabs) update in up to OPT_REST_CHUNKS row blocks, streamed
-    # in/out per block and written back in place, so the remainder working
-    # set is ~2 blocks of state, not the whole fp32 embedding state; small
-    # leaves go in one shot (a loop per norm vector would only bloat compile
-    # time). The planner prices the same rule.
+    # production vocabs) sweep in up to OPT_REST_CHUNKS row blocks, so the
+    # remainder working set is ~2 blocks of state, not the whole fp32
+    # embedding state; small leaves go in one shot (a loop per norm vector
+    # would only bloat compile time). The planner prices the same rule.
+    # Remainder grads never leave HBM: the host sink and the post-hoc
+    # placement move the stacks only.
     def rest_leaf(g, *rest_leaves):
         """One remainder leaf set -> tuple of updated leaves
         ((state..., new_param) layout matching the stack sweep)."""
-        p_like = rest_leaves[-1]          # param leaf (dtype; sgdm: value)
-        ss = rest_leaves[:-1]
-        pdt = p_like.dtype                # static, no data dependency
-
-        def one_shot(g1, ss1, p1):
-            ss1 = stream_layer_to_device(ss1, cls="optimizer")
-            g1 = clip_leaf(stream_layer_to_device(g1, cls="grads"),
-                           clip_scale)
-            if needs_params:
-                m2, p2 = kernel(g1, ss1[0],
-                                stream_layer_to_device(p1, cls="params"))
-                out = (m2,)
-            else:
-                m2, v2, mp2 = kernel(g1, ss1[0], ss1[1], ss1[2])
-                out, p2 = (m2, v2, mp2), mp2.astype(pdt)
-            if params_host:
-                p2 = stream_layer_to_host(p2, cls="params")
-            return stream_layer_to_host(out, cls="optimizer") + (p2,)
-
+        ss, p = rest_leaves[:-1], rest_leaves[-1]
         c = rest_chunks(g.shape, g.size)
-        if c <= 1:
-            return one_shot(g, ss, p_like)
-        rows = g.shape[0] // c
-
-        def cbody(i, outs):
-            got = one_shot(jax.lax.dynamic_slice_in_dim(g, i * rows, rows),
-                           tuple(jax.lax.dynamic_slice_in_dim(x, i * rows, rows)
-                                 for x in outs[:-1]),
-                           jax.lax.dynamic_slice_in_dim(outs[-1], i * rows,
-                                                        rows))
-            return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, i * rows, 0)
-                         for o, y in zip(outs, got))
-
-        outs = jax.lax.fori_loop(0, c, cbody, tuple(ss) + (p_like,))
-        tail = g.shape[0] - c * rows
-        if not tail:
-            return outs
-        # leftover rows of a leading dim the block count does not divide
-        cut = lambda x: jax.lax.slice_in_dim(x, c * rows, g.shape[0])
-        got = one_shot(cut(g), tuple(cut(x) for x in outs[:-1]),
-                       cut(outs[-1]))
-        return tuple(jax.lax.dynamic_update_slice_in_dim(o, y, c * rows, 0)
-                     for o, y in zip(outs, got))
+        if c > 1:
+            rows = g.shape[0] // c
+            states, p2 = sweep("rows", g, ss, p, False, c, rows,
+                               g.shape[0] - c * rows)
+        else:
+            states, p2 = sweep("rows", g, ss, p, False)
+        return tuple(states) + (p2,)
 
     rest_in = ((g_rest,) + tuple(s_rests) + (p_rest,))
     outs = _map_kernel(rest_leaf, nstate + 1, *rest_in)
@@ -458,6 +518,10 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
 
     pshapes, pspecs = model.abstract_params(mesh)
     overlap = _resolve_overlap(overlap_grads, plan, tcfg, mean_over)
+    # the stacked grads reach the optimizer sweep host-resident: sunk per
+    # layer by the m==1 hooks, or placed post hoc without the hooks
+    grads_sunk = (grads_host and opt_stream is not None
+                  and (m == 1 or not overlap))
     hooks = None
     if overlap:
         # per-layer reduce inside the scan backward; with accumulation the
@@ -473,9 +537,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
             _stack_group_specs(pspecs), ddl, data_axis="data",
             pod_axis=pod_axis, data_size=data_size, pod_size=pod_size,
             keep="shard" if m > 1 else "full",
-            sink=(effective_kind("pinned_host")
-                  if grads_host and m == 1 and opt_stream is not None
-                  else None))
+            sink=effective_kind("pinned_host") if grads_sunk else None)
     if overlap and m > 1:
         stacked = _stacked_mask(pshapes)
         sspec = ddl_overlap.shard_spec(pshapes, data_size, stacked)
@@ -541,7 +603,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
             grads, _ = ddl_reduce_tree(grads, ddl, data_axis="data",
                                        pod_axis=pod_axis, data_size=data_size,
                                        pod_size=pod_size, param_specs=pspecs)
-            if grads_host and opt_stream is not None:
+            if grads_sunk:
                 # no in-scan hooks to sink per layer: honor the residency
                 # with a post-hoc placement of the stacked grads, which the
                 # streamed optimizer sweep then reads back layer by layer
@@ -577,7 +639,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
                     tcfg.optimizer, grads, opt_state, params, cfg=cfg, lr=lr,
                     beta1=tcfg.beta1, beta2=tcfg.beta2,
                     weight_decay=tcfg.weight_decay, clip_scale=scale,
-                    schedule=opt_stream, params_host=params_host)
+                    params_host=params_host, grads_host=grads_sunk)
         else:
             grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
             new_params, new_opt = opt_update(

@@ -53,15 +53,29 @@ def _run_steps(model, tcfg, mesh, plan, batch, steps=3):
 
 
 # ---------------------------------------------------------------------------
-# Exact streamed-vs-resident parity (single device; adamw + sgdm; depth 2
-# regroups the sweep to 2 layers per iteration — still exact, the update is
-# elementwise)
+# Exact streamed-vs-resident parity (single device; adamw + sgdm). The
+# sweep is a pipeline over layers: a 1-layer stack is its first unit and
+# last write-back alone, an odd layer count leaves nothing to pair; params
+# on host add the param write-back (and sgdm's param swap-in) to it.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("optimizer", ["adamw", "sgdm"])
-@pytest.mark.parametrize("microbatches", [1, 2])
-def test_streamed_opt_exactly_matches_resident(optimizer, microbatches):
-    cfg = get_smoke_config("olmo-1b")
+PARITY_CASES = [
+    pytest.param("adamw", 1, 2, "device", id="1-adamw"),
+    pytest.param("sgdm", 1, 2, "device", id="1-sgdm"),
+    pytest.param("adamw", 2, 2, "device", id="2-adamw"),
+    pytest.param("sgdm", 2, 2, "device", id="2-sgdm"),
+    pytest.param("adamw", 1, 1, "device", id="1-adamw-one_layer"),
+    pytest.param("sgdm", 1, 3, "device", id="1-sgdm-odd_layers"),
+    pytest.param("adamw", 1, 3, "host", id="1-adamw-odd_layers-params_host"),
+    pytest.param("sgdm", 1, 1, "host", id="1-sgdm-one_layer-params_host"),
+    pytest.param("sgdm", 2, 2, "host", id="2-sgdm-params_host"),
+]
+
+
+@pytest.mark.parametrize("optimizer,microbatches,layers,params", PARITY_CASES)
+def test_streamed_opt_exactly_matches_resident(optimizer, microbatches,
+                                               layers, params):
+    cfg = dataclasses.replace(get_smoke_config("olmo-1b"), num_layers=layers)
     model = Model(cfg, attn_impl="naive")
     mesh_spec = MeshSpec((1, 1), ("data", "model"))
     mesh = make_mesh(mesh_spec)
@@ -69,10 +83,13 @@ def test_streamed_opt_exactly_matches_resident(optimizer, microbatches):
                        mesh=mesh_spec, ddl=DDLConfig(mode="allreduce"),
                        warmup_steps=1, learning_rate=1e-2, total_steps=10,
                        optimizer=optimizer, microbatches=microbatches)
-    plan = _plan(cfg, {"params": "device", "grads": "device",
-                       "optimizer": "host", "kvcache": "device"})
+    # streamed params at depth 1: a deeper prefetch regroups the layer
+    # scan, which is not bitwise the resident scan
+    plan = _plan(cfg, {"params": params, "grads": "device",
+                       "optimizer": "host", "kvcache": "device"},
+                 depth=1 if params == "host" else 2)
     assert plan.swap_schedule.streams_optimizer
-    assert not plan.swap_schedule.streams_params
+    assert plan.swap_schedule.streams_params == (params == "host")
     batch = _batch(cfg)
     ms_res, st_res = _run_steps(model, tcfg, mesh, None, batch)
     ms_str, st_str = _run_steps(model, tcfg, mesh, plan, batch)
@@ -110,25 +127,32 @@ def test_full_residency_streamed_step_exact_single_device():
                                       np.asarray(y, np.float32))
 
 
-@pytest.mark.parametrize("rows", [4096, 4099])
-def test_streamed_rest_chunking_exact(rows):
+@pytest.mark.parametrize("rows,optimizer,params_host", [
+    pytest.param(4096, "adamw", False, id="4096"),
+    pytest.param(4099, "adamw", False, id="4099"),
+    pytest.param(1, "adamw", False, id="1-one_block"),
+    pytest.param(2, "sgdm", False, id="2-two_blocks-sgdm"),
+    pytest.param(4099, "sgdm", True, id="4099-sgdm-params_host"),
+])
+def test_streamed_rest_chunking_exact(rows, optimizer, params_host):
     """Remainder leaves >= 1M elements take the row-block path (the fp32
     embedding state must not land in HBM whole); blocks are slices around
     the same elementwise kernel, so it stays exact. 4099 rows leave a
-    3-row tail after 16 blocks (an odd vocab)."""
-    from repro.optim.adamw import (adamw_init, adamw_update,
-                                   clip_by_global_norm, clip_scale,
-                                   global_norm)
+    3-row tail after 16 blocks (an odd vocab): the tail block is the
+    pipeline's last unit. One row is one block (the one-shot path); two
+    rows are two blocks, the pipeline's first and last units alone."""
+    from repro.optim.adamw import (OPTIMIZERS, clip_by_global_norm,
+                                   clip_scale, global_norm)
     from repro.train.steps import _streamed_opt_update
     cfg = get_smoke_config("olmo-1b")          # stack_plan: one 2-layer scan
     rng = np.random.default_rng(0)
     f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
-    params = {"embed": {"w": f32(rows, 256)},  # >= 2^20 elements -> blocks
+    params = {"embed": {"w": f32(rows, max(256, (1 << 20) // rows))},
               "decoder": {"stack0": {"w": f32(cfg.num_layers, 8, 8)}},
               "final_norm": {"scale": f32(8)}}
     grads = jax.tree.map(lambda p: f32(*p.shape), params)
-    state = adamw_init(params)
-    sched = make_swap_schedule({"optimizer": "host"}, cfg.num_layers, "train")
+    init, update = OPTIMIZERS[optimizer]
+    state = init(params)
     kw = dict(lr=0.1, beta1=0.9, beta2=0.95, weight_decay=0.1)
 
     # jit both legs, as the step builder does — eager op-by-op dispatch vs
@@ -136,20 +160,85 @@ def test_streamed_rest_chunking_exact(rows):
     @jax.jit
     def ref(g, s, p):
         gc, _ = clip_by_global_norm(g, 1.0)
-        return adamw_update(gc, s, p, **kw)
+        return update(gc, s, p, **kw)
 
     @jax.jit
     def streamed(g, s, p):
         return _streamed_opt_update(
-            "adamw", g, s, p, cfg=cfg,
+            optimizer, g, s, p, cfg=cfg,
             clip_scale=clip_scale(global_norm(g), 1.0),
-            schedule=sched, params_host=False, **kw)
+            params_host=params_host, grads_host=False, **kw)
 
     ref_p, ref_s = ref(grads, state, params)
     new_p, new_s = streamed(grads, state, params)
     for a, b in zip(jax.tree.leaves((ref_p, ref_s)),
                     jax.tree.leaves((new_p, new_s))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_sweep_unit_counters_and_swapped_units():
+    """The traced sweep loops count their units and the units whose
+    swap-in shares the link with the previous unit's write-back: all but
+    the first, (n-1)/n. Each sweep traces its swap-in twice, for unit 0 in
+    a loop of its own and for unit i in the pipeline's body, so the
+    optimizer bytes swapped in at trace time are two units of each sweep:
+    the units the planner prices as in HBM at once."""
+    from repro import obs
+    from repro.core.lms.planner import OPT_SWEEP_UNITS, opt_sweep_elements
+    from repro.train.steps import build_train_step, init_train_state
+    cfg = dataclasses.replace(get_smoke_config("olmo-1b"), num_layers=4,
+                              vocab_size=16384)   # 16384 x 64: 16 blocks
+    model = Model(cfg, attn_impl="naive")
+    mesh_spec = MeshSpec((1, 1), ("data", "model"))
+    tcfg = TrainConfig(model=cfg, shape=ShapeConfig("smoke", "train", 16, 2),
+                       mesh=mesh_spec, ddl=DDLConfig(mode="allreduce"))
+    plan = _plan(cfg, {"params": "device", "grads": "device",
+                       "optimizer": "host", "kvcache": "device"})
+    reg = obs.reset().registry
+    fn, _, _ = build_train_step(model, tcfg, make_mesh(mesh_spec), plan=plan,
+                                donate=False)
+    state = jax.eval_shape(
+        lambda: init_train_state(model, tcfg, jax.random.key(0)))
+    fn.lower(state, jax.eval_shape(lambda: _batch(cfg)))
+    got = {k: reg.counter(f"lms.sweep_units{a}.{k}").value
+           for k in ("stack", "rows") for a in ("", "_ahead")}
+    assert reg.counter("lms.sweep_units.stack").value == 4
+    assert reg.counter("lms.sweep_units_ahead.stack").value == 3
+    assert reg.counter("lms.sweep_units.rows").value == 16
+    assert reg.counter("lms.sweep_units_ahead.rows").value == 15, got
+    layer = sum(int(np.prod(x.shape[1:]))
+                for x in jax.tree.leaves(state.params["decoder"]["stack0"]))
+    block = 16384 // 16 * cfg.d_model
+    assert OPT_SWEEP_UNITS == 2
+    assert opt_sweep_elements(cfg) == OPT_SWEEP_UNITS * (layer + block)
+    assert (reg.counter("lms.swap_in_bytes.optimizer").value
+            == 12 * OPT_SWEEP_UNITS * (layer + block))
+    obs.reset()
+
+
+def test_planner_prices_the_olmo_1b_sweep():
+    """The benchmark cell's plan (olmo-1b, 4 x 2048 tokens, one v5e): the
+    AdamW state on host and streamed once each way a step, params and
+    grads in HBM; the sweep priced at the executor's units in flight, one
+    layer (4 d^2 attention + 3 d d_ff MLP) and one of the embedding's 16
+    row blocks each, 12 bytes of AdamW state per parameter."""
+    from repro import hw
+    from repro.core.lms.planner import (OPT_SWEEP_UNITS, PlanRequest, plan,
+                                        opt_sweep_elements)
+    cfg = get_config("olmo-1b")
+    p = plan(PlanRequest(cfg=cfg, shape=ShapeConfig("c", "train", 2048, 4),
+                         mesh=MeshSpec((1, 1), ("data", "model")),
+                         hw=hw.TPU_V5E))
+    assert {k: p.residency[k] for k in ("optimizer", "params", "grads")} == {
+        "optimizer": "host", "params": "device", "grads": "device"}
+    assert p.swap_bytes_per_step == 28_242_345_984
+    assert p.swap_schedule.bytes_for("optimizer") == 28_242_345_984
+    assert p.fits
+    d, d_ff = 2048, 8192
+    layer = 4 * d * d + 3 * d * d_ff
+    block = 50304 // 16 * d
+    assert opt_sweep_elements(cfg) == OPT_SWEEP_UNITS * (layer + block)
+    assert 12 * opt_sweep_elements(cfg) == 1_765_146_624
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,9 @@ collective fusions that miscompiled it turned off.
 The topology is described inside a module-scoped fixture, never at import:
 only the process given this file loads the TPU compiler library.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,17 +138,193 @@ def test_ssd_scan_compiles(one_chip):
              ((b, l, g, n), jnp.bfloat16), ((b, l, g, n), jnp.bfloat16))
 
 
-def test_train_step_with_host_optimizer_compiles(one_chip, monkeypatch):
+# Pinned-host transfers of the v5e link as the recorded chip trace of the
+# olmo-1b cell shows them: 14.2 GB/s in one direction alone, 13.3 GB/s in
+# each direction when both carry traffic (benchmarks/chip/fixtures).
+LINK_ONE_WAY, LINK_EACH_WAY = 14.2e9, 13.3e9
+
+
+def _hlo(text):
+    """Compiled HLO text -> {computation: [instruction]}; an instruction
+    is a dict with its name, shape, opcode, operand names and line."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps[head.group(1)] = []
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line)
+        if not m or cur is None:
+            continue
+        i, depth = m.end(), 0
+        while True:               # the shape: a tuple in parens or a word
+            depth += {"(": 1, ")": -1}.get(line[i], 0)
+            i += 1
+            if depth == 0 and line[i] == " ":
+                break
+        op, _, rest = line[i + 1:].partition("(")
+        args, depth = "", 1
+        for ch in rest:
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                break
+            args += ch
+        cur.append({"name": m.group(1), "shape": line[m.end():i],
+                    "op": op, "args": re.findall(r"%([\w.\-]+)", args),
+                    "line": line})
+    return comps
+
+
+def _nbytes(shape):
+    dt, dims = re.match(r"(\w+)\[([\d,]*)\]", shape).groups()
+    return (int(np.prod([int(d) for d in dims.split(",") if d]))
+            * {"f32": 4, "bf16": 2}[dt])
+
+
+def _host_transfers(body):
+    """The body's pinned-host transfers in schedule order: (kind, start
+    name, bytes), kind R> / R< a read (host to HBM) issued / waited for,
+    W> / W< a write."""
+    by = {x["name"]: x for x in body}
+    out = []
+    for x in body:
+        if x["op"].endswith("-start") and "S(5)" in x["shape"]:
+            shapes = re.findall(r"\w+\[[\d,]*\]", x["shape"])
+            if x["op"] == "dynamic-slice-start":
+                out.append(("R>", x["name"], _nbytes(shapes[-2])))
+            elif x["op"] == "dynamic-update-slice-start":
+                out.append(("W>", x["name"], _nbytes(shapes[1])))
+        elif x["op"].endswith("-done") and x["args"][0] in by:
+            start = by[x["args"][0]]
+            if "S(5)" in start["shape"] and start["op"] in (
+                    "dynamic-slice-start", "dynamic-update-slice-start"):
+                kind = "R<" if start["op"] == "dynamic-slice-start" else "W<"
+                out.append((kind, start["name"], 0))
+    return out
+
+
+def _link_seconds(transfers):
+    """One iteration on the host link: each direction serves its transfers
+    in the order they are issued, a -done waits for its transfer, compute
+    is free. The model reproduces the recorded chip trace's iteration
+    times of the sweep this step had before its pipeline (147.5 ms against
+    147.7 for two layers of olmo-1b's stack, 10.9 against 11.0 for a block
+    of its embedding rows)."""
+    queues, done, t = {"R": [], "W": []}, set(), 0.0
+
+    def run_until(finished):
+        nonlocal t
+        while not finished():
+            busy = [k for k in queues if queues[k]]
+            bw = LINK_ONE_WAY if len(busy) == 1 else LINK_EACH_WAY
+            dt = min(queues[k][0][1] for k in busy) / bw
+            for k in busy:
+                queues[k][0][1] -= dt * bw
+                if queues[k][0][1] <= 1.0:
+                    done.add(queues[k].pop(0)[0])
+            t += dt
+
+    for kind, name, nbytes in transfers:
+        if kind[1] == ">":
+            queues[kind[0]].append([name, float(nbytes)])
+        else:
+            run_until(lambda: name in done)
+    run_until(lambda: not queues["R"] and not queues["W"])
+    return t
+
+
+def _sweep_loops(comps):
+    """-> [(while instruction, body)] of the streamed optimizer sweep."""
+    loops = []
+    for ins in comps.values():
+        for x in ins:
+            if x["op"] == "while" and "/lms.opt_sweep/" in x["line"]:
+                body = re.search(r"body=%([\w.\-]+)", x["line"]).group(1)
+                loops.append((x, comps[body]))
+    return loops
+
+
+def _check_pipelined_sweep(text, num_layers):
+    """The sweep loops of a compiled host-optimizer step are software
+    pipelines: each iteration swaps a unit in while the previous unit's
+    results go back out, so both link directions carry traffic at once."""
+    comps = _hlo(text)
+    # the host-resident state stacks are sliced in place, never copied
+    assert not [x["name"] for ins in comps.values() for x in ins
+                if x["op"] in ("copy", "copy-start")
+                and re.search(rf"\[{num_layers},[^\]]*\]\{{[^}}]*S\(5\)",
+                              x["shape"])]
+    kinds = set()
+    for loop, body in _sweep_loops(comps):
+        by = {x["name"]: x for x in body}
+        transfers = _host_transfers(body)
+        reads = {n for k, n, _ in transfers if k == "R>"}
+        writes = [n for k, n, _ in transfers if k == "W>"]
+        assert reads
+        if not writes:             # the loop of unit 0
+            continue
+        carried = re.findall(r"\w+\[(\d+)[\d,]*\]\{[^}]*S\(5\)\}",
+                             loop["shape"])
+        kind = "stack" if str(num_layers) in carried else "rows"
+        kinds.add(kind)
+        if kind == "stack":        # a unit is one layer
+            for n in reads:
+                moved = re.findall(r"\w+\[([\d,]*)\]", by[n]["shape"])[-2]
+                assert moved.startswith("1,"), moved
+        # what each write-back carries was computed in the previous
+        # iteration, not from a swap-in of this one: the write-backs need
+        # not wait for the swap-ins
+        for w in writes:
+            seen, todo = set(), [by[w]["args"][1]]
+            while todo:
+                x = by.get(todo.pop())
+                if x is None or x["name"] in seen:
+                    continue
+                seen.add(x["name"])
+                assert not (x["op"] == "dynamic-slice-done"
+                            and x["args"][0] in reads), (w, x["name"])
+                todo += x["args"]
+        if len({by[n]["args"][0] for n in reads}) < 2:
+            continue               # one array's transfers are serial
+        # write-backs are issued before the swap-ins have landed, and the
+        # modelled link moves the iteration's bytes in well under the time
+        # of moving them one direction after the other
+        order = [k for k, _, _ in transfers]
+        assert order.index("W>") < len(order) - order[::-1].index("R<") - 1
+        serial = sum(b for _, _, b in transfers) / LINK_ONE_WAY
+        assert _link_seconds(transfers) <= 0.8 * serial
+    assert kinds == {"stack", "rows"}, kinds
+
+
+@pytest.mark.parametrize("case", ["smoke", "adamw_deep", "sgdm_deep",
+                                  "adamw_biases"])
+def test_train_step_with_host_optimizer_compiles(one_chip, monkeypatch, case):
     """Host residency is real on TPU only; steer the placement decision to
     the TPU branch so the step carries pinned-host shardings, in-jit
-    transfers and the streamed optimizer sweep, as it does on the chip."""
+    transfers and the streamed optimizer sweep, as it does on the chip.
+    The other cases (8 layers, 128-wide heads, an embedding of 4096 x 256
+    that the sweep takes in 16 row blocks) check the compiled schedule of
+    the sweep: `_check_pipelined_sweep`. The biases case has starcoder2's
+    small [L, 36, 128] query-bias stacks, whose layer axis the TPU layout
+    tiles, and one-shot norm leaves."""
     monkeypatch.setattr(compat, "host_memory_kind", lambda: "pinned_host")
     cfg = get_smoke_config("olmo-1b")
+    optimizer = case.split("_")[0] if case != "smoke" else "adamw"
+    if case.endswith("_deep"):
+        cfg = dataclasses.replace(cfg, num_layers=8, d_model=256,
+                                  num_heads=2, num_kv_heads=2, head_dim=128,
+                                  d_ff=256, vocab_size=4096)
+    elif case.endswith("_biases"):
+        cfg = dataclasses.replace(get_smoke_config("starcoder2-7b"),
+                                  num_layers=8, d_model=256, num_heads=36,
+                                  num_kv_heads=4, head_dim=128, d_ff=256,
+                                  vocab_size=4096)
     model = Model(cfg)
     mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
                 ("data", "model"))
     tcfg = TrainConfig(model=cfg, shape=ShapeConfig("s", "train", 128, 4),
-                       mesh=MeshSpec((1, 1), ("data", "model")))
+                       mesh=MeshSpec((1, 1), ("data", "model")),
+                       optimizer=optimizer)
     residency = {"params": "device", "grads": "device",
                  "optimizer": "host", "kvcache": "device"}
     plan = MemoryPlan({}, residency, 1, 1, 1, 1, True,
@@ -175,6 +353,8 @@ def test_train_step_with_host_optimizer_compiles(one_chip, monkeypatch):
     for scope in ("/jvp(train.layers)/", "/transpose(jvp(train.layers))/",
                   "/lms.opt_sweep/"):
         assert scope in text, scope
+    if case != "smoke":
+        _check_pipelined_sweep(text, cfg.num_layers)
 
 
 def test_overlapped_ddl_step_compiles_without_collective_fusions(topo):
