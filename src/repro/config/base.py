@@ -35,10 +35,26 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
 
-    # MoE
+    # MoE: a held-experts layer (models/moe.py) routes over all
+    # `num_experts` and computes the `experts_held` of them from
+    # `experts_offset` on (0 held: all), dropping no token
     num_experts: int = 0
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    experts_held: int = 0
+    experts_offset: int = 0
+    shared_experts: int = 0           # always-on experts, d_ff wide each
+    router_score: str = "softmax"     # softmax | sigmoid
+    router_scale: float = 1.0         # on the top-k weights, renormalised
+    router_bias_rate: float = 0.0     # selection-bias step per train step
+    router_aux_weight: float = 0.01   # balance-loss weight
+    first_dense_layers: int = 0       # leading dense layers, outside the stack
+    dense_d_ff: int = 0               # their MLP width
+
+    # latent attention (MLA): kv_lora_rank > 0; head_dim is the q/k width
+    # (nope + rope), v_head_dim the value width
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM (Mamba-2 / SSD)
     ssm_state: int = 0
@@ -63,6 +79,24 @@ class ModelConfig:
 
     def __post_init__(self):
         assert self.family in FAMILIES, self.family
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r}: softmax "
+                             "or sigmoid")
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts this chip computes."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def stack_layers(self) -> int:
+        """Layers of the scanned decoder stack (the leading dense layers
+        are not in it)."""
+        return self.num_layers - self.first_dense_layers
 
     # ---- derived properties -------------------------------------------------
     @property
@@ -97,7 +131,7 @@ class ModelConfig:
         if self.family == "hybrid" and self.block_pattern:
             pat = self.block_pattern
             return tuple(pat[i % len(pat)] for i in range(self.num_layers))
-        return ("attn",) * self.num_layers
+        return ("mla" if self.is_mla else "attn",) * self.stack_layers
 
     # ---- parameter counting (used by planner + roofline MODEL_FLOPS) -------
     def param_count(self) -> int:
@@ -125,7 +159,13 @@ class ModelConfig:
         n_ssd = sum(1 for k in kinds if k == "ssd")
         n_rglru = sum(1 for k in kinds if k == "rglru")
 
-        if n_attn:
+        if self.is_mla:
+            h, r, dr = self.num_heads, self.kv_lora_rank, self.qk_rope_dim
+            dn, dv = self.head_dim - dr, self.v_head_dim
+            per = (d * h * (dn + dr) + d * (r + dr) + r + r * h * (dn + dv)
+                   + h * dv * d)
+            out.append(("attn", self.num_layers * per))
+        elif n_attn:
             q = d * self.num_heads * self.head_dim + (self.num_heads * self.head_dim if self.qkv_bias or self.use_bias else 0)
             kv = 2 * (d * self.num_kv_heads * self.head_dim + (self.num_kv_heads * self.head_dim if self.qkv_bias or self.use_bias else 0))
             o = self.num_heads * self.head_dim * d + (d if self.use_bias else 0)
@@ -148,10 +188,17 @@ class ModelConfig:
 
         # MLP / MoE per decoder layer
         n_mlp_layers = self.num_layers if self.family != "ssm" else 0
+        if self.first_dense_layers:
+            out.append(("lead_mlp",
+                        self.first_dense_layers * 3 * d * self.dense_d_ff))
+            n_mlp_layers -= self.first_dense_layers
         if self.num_experts:
             per_expert = 3 * d * self.d_ff  # gated
-            out.append(("moe_experts", n_mlp_layers * self.num_experts * per_expert))
+            out.append(("moe_experts", n_mlp_layers * self.held * per_expert))
             out.append(("router", n_mlp_layers * d * self.num_experts))
+            if self.shared_experts:
+                out.append(("moe_shared", n_mlp_layers * self.shared_experts
+                            * per_expert))
         elif n_mlp_layers:
             if self.mlp_act in ("swiglu", "geglu"):
                 per = 3 * d * self.d_ff + (2 * self.d_ff + d if self.use_bias else 0)

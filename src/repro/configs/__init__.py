@@ -10,11 +10,13 @@ from repro.configs import (
     recurrentgemma_9b,
     qwen2_vl_2b,
     whisper_tiny,
+    moonlight_16b_a3b,
 )
 
 _MODULES = (
     qwen2_5_14b, olmo_1b, starcoder2_7b, qwen2_72b, mamba2_1_3b,
     grok_1_314b, qwen3_moe_235b, recurrentgemma_9b, qwen2_vl_2b, whisper_tiny,
+    moonlight_16b_a3b,
 )
 
 REGISTRY = {m.ARCH_ID: m for m in _MODULES}

@@ -420,12 +420,22 @@ def activation_classes(cfg: ModelConfig, shape: ShapeConfig,
     bs2 = b * s * 2  # bf16
     out: List[TensorClass] = []
     kinds = cfg.layer_kinds()
-    has_attn = any(k in ("attn", "local_attn") for k in kinds)
+    has_attn = any(k in ("attn", "local_attn", "mla") for k in kinds)
     # residual stream + norms are unsharded across model
     out.append(TensorClass("resid", bs2 * d, 0.0))
     out.append(TensorClass("attn_norm" if has_attn else "ln_in", bs2 * d,
                            2.0 * b * s * d))
-    if has_attn:
+    if cfg.is_mla:
+        # q and k at head_dim (nope + rope), v at v_head_dim
+        h, dv, r = cfg.num_heads, cfg.v_head_dim, cfg.kv_lora_rank
+        hq = h * (2 * cfg.head_dim + dv)
+        proj = d * (h * cfg.head_dim + r + cfg.qk_rope_dim) \
+            + r * h * (cfg.head_dim - cfg.qk_rope_dim + dv)
+        out.append(TensorClass("qkv", bs2 * hq // tp,
+                               2.0 * b * s * proj / tp))
+        out.append(TensorClass("attn_out", bs2 * h * dv // tp,
+                               2.0 * b * s * s * h * (cfg.head_dim + dv) / tp))
+    elif has_attn:
         hq = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
         out.append(TensorClass("qkv", bs2 * hq // tp, 2.0 * b * s * d * hq / tp))
         out.append(TensorClass("attn_out", bs2 * hq // tp,
@@ -441,11 +451,19 @@ def activation_classes(cfg: ModelConfig, shape: ShapeConfig,
         w = cfg.lru_width or d
         out.append(TensorClass("lru_h", bs2 * w // tp, 4.0 * b * s * w * w / tp))
     if cfg.num_experts:
-        cap_rows = int(b * s * cfg.experts_per_token * cfg.moe_capacity_factor)
-        out.append(TensorClass("moe_hidden", cap_rows * f * 2 // tp,
-                               2.0 * cap_rows * d * f / tp))
+        # dropless dispatch: a row for every pick, of which the held
+        # experts' are computed
+        rows = b * s * cfg.experts_per_token
+        held = rows * cfg.held // cfg.num_experts
+        out.append(TensorClass("moe_hidden", rows * f * 2 // tp,
+                               2.0 * held * d * f / tp))
         out.append(TensorClass("router_probs", b * s * cfg.num_experts * 4,
                                2.0 * b * s * d * cfg.num_experts))
+        if cfg.shared_experts:
+            fs = cfg.shared_experts * f
+            out.append(TensorClass("mlp_hidden", 3 * bs2 * fs // tp,
+                                   6.0 * b * s * d * fs / tp))
+            out.append(TensorClass("mlp_norm", bs2 * d, 2.0 * b * s * d))
     elif cfg.family != "ssm":
         gated = cfg.mlp_act in ("swiglu", "geglu")
         mult = 3 if gated else 2  # g, u, h tagged together
@@ -453,6 +471,28 @@ def activation_classes(cfg: ModelConfig, shape: ShapeConfig,
                                2.0 * mult * b * s * d * f / tp))
         out.append(TensorClass("mlp_norm", bs2 * d, 2.0 * b * s * d))
     return out
+
+
+def layer_work_bytes(cfg: ModelConfig, shape: ShapeConfig,
+                     mesh: MeshSpec, chunk: int = 512) -> int:
+    """What one layer's backward holds at once besides its saved
+    activations, for the layer kinds whose working set outgrows the largest
+    activation class (0 for the others): latent attention's last query
+    block (f32 scores, probabilities and their gradient, [B,H,chunk,S]
+    each) and the dropless expert dispatch's row buffers (the gathered rows
+    and the per-pick outputs, [T*k, d] each, bf16, and their gradients; its
+    hidden units are the `moe_hidden` class), whichever is larger."""
+    dp = _axis_size(mesh, "data") * _axis_size(mesh, "pod")
+    tp = _axis_size(mesh, "model")
+    b = max(shape.global_batch // dp, 1)
+    s = shape.seq_len
+    work = 0
+    if cfg.is_mla:
+        work = 3 * 4 * b * cfg.num_heads * min(chunk, s) * s // tp
+    if cfg.num_experts:
+        rows = b * s * cfg.experts_per_token
+        work = max(work, 2 * 4 * rows * cfg.d_model // tp)
+    return work
 
 
 def layer_flops_dev(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec) -> float:
@@ -898,7 +938,8 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
                                   if assignment[a.name] == "save")
     offload_bytes = lambda: L * sum(a.bytes_dev for a in acts
                                     if assignment[a.name] == "offload")
-    transient = max((a.bytes_dev for a in acts), default=0) * 4
+    transient = (max((a.bytes_dev for a in acts), default=0) * 4
+                 + layer_work_bytes(cfg, shape, mesh))
 
     def fixed():
         return params_dev + grads_dev + opt_dev + transient

@@ -1,4 +1,4 @@
-"""GQA attention: parameter defs, three interchangeable implementations
+"""GQA and latent (MLA) attention: parameter defs, three interchangeable implementations
 (naive oracle / blockwise online-softmax / Pallas flash kernel), causal and
 local-window masking, and KV-cache decode paths.
 
@@ -57,6 +57,49 @@ def project_qkv(cfg, p, x, kv_x=None):
     return q, k, v
 
 
+def mla_defs(cfg):
+    """Latent attention without q-LoRA: q = x wq; c = x wkv_a; k_pe =
+    x wk_rope (k_pe is shared by the heads); [k_nope; v] = RMSNorm(c)
+    wkv_b per head. The published [d, 576] wkv_a is kept as its two parts
+    and wq as [d, heads * (nope + rope)]: host arrays [d, 576] and
+    [d, heads, 192] get layouts whose row blocks the TPU cannot stream."""
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dr, dv = cfg.qk_rope_dim, cfg.v_head_dim
+    dn = cfg.head_dim - dr
+    scale_out = 0.02 / math.sqrt(2 * cfg.num_layers)
+    return {
+        "wq": ParamDef((d, h * (dn + dr)), ("d_model", "heads")),
+        "wkv_a": ParamDef((d, r), ("d_model", None)),
+        "wk_rope": ParamDef((d, dr), ("d_model", None)),
+        "kv_norm": ParamDef((r,), (None,), init="ones", dtype="float32"),
+        "wkv_b": ParamDef((r, h, dn + dv), (None, "heads", "head_dim")),
+        "wo": ParamDef((h, dv, d), ("heads", "head_dim", "d_model"),
+                       scale=scale_out),
+    }
+
+
+def project_mla(cfg, p, x, positions):
+    """-> q, k [B,S,H,nope+rope] (RoPE on the rope part), v [B,S,H,dv]."""
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
+    dn = cfg.head_dim - dr
+    q = (x @ p["wq"]).reshape(x.shape[:2] + (cfg.num_heads, dn + dr))
+    k_pe = x @ p["wk_rope"]
+    cf = (x @ p["wkv_a"]).astype(jnp.float32)
+    cf = cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True)
+                            + cfg.norm_eps) * p["kv_norm"]
+    kvb = jnp.einsum("bsr,rhk->bshk", cf.astype(x.dtype), p["wkv_b"])
+    k_nope, v = kvb[..., :dn], kvb[..., dn:]
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], -1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_pe, k_nope.shape[:3] + (dr,))], -1)
+    q = tag(constrain(q, "batch", "seq", "heads", None), "qkv")
+    k = tag(constrain(k, "batch", "seq", "heads", None), "qkv")
+    v = tag(constrain(v, "batch", "seq", "heads", None), "qkv")
+    return q, k, v
+
+
 def out_proj(cfg, p, o):
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     if "bo" in p:
@@ -95,18 +138,36 @@ def naive_attention(q, k, v, *, causal: bool, window: int = 0,
     scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v.dtype), v)
-    return o.reshape(b, sq, h, d)
+    return o.reshape(b, sq, h, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
-# Blockwise (flash-style, pure jnp, scan over KV chunks)
+# Blockwise (flash-style, pure jnp: causal query blocks or a scan over KV
+# chunks)
 # ---------------------------------------------------------------------------
+
+# the largest f32 probability matrix, [B,H,Sq,Skv], that causal
+# self-attention keeps for its backward through the KV-chunk scan
+# (olmo-1b's 4 x 2048 tokens of 16 heads: 1.07 GB); past it, query blocks
+KV_SCAN_MAX_SCORE_BYTES = 2 << 30
+
 
 def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
                         chunk: int = 512, q_offset: int = 0):
-    """Online-softmax over KV chunks; O(Sq·chunk) live memory. Matches
-    naive_attention to fp32-accumulation tolerance."""
+    """Exact attention in blocks of `chunk`; matches naive_attention to
+    fp32-accumulation tolerance. v may be narrower or wider than q and k
+    (latent attention: 128 against 192).
+
+    An online softmax over KV chunks, O(Sq·chunk) live memory; its
+    backward keeps every chunk's f32 probabilities, [B,H,Sq,Skv] in all.
+    Causal self-attention from position 0 with no window whose
+    probabilities would outgrow `KV_SCAN_MAX_SCORE_BYTES` runs by query
+    blocks instead (`_causal_query_blocks`), which keep none."""
     b, sq, h, d = q.shape
+    if (causal and not window and q_offset == 0 and sq == k.shape[1]
+            and 4 * b * h * sq * sq > KV_SCAN_MAX_SCORE_BYTES):
+        return _causal_query_blocks(q, k, v, block=chunk)
+    dv = v.shape[-1]
     kh = k.shape[2]
     g = h // kh
     skv = k.shape[1]
@@ -120,7 +181,7 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
     qpos = jnp.arange(sq) + q_offset
 
     kc = k.reshape(b, nkc, chunk, kh, d)
-    vc = v.reshape(b, nkc, chunk, kh, d)
+    vc = v.reshape(b, nkc, chunk, kh, dv)
 
     def body(carry, inputs):
         m, l, acc = carry
@@ -144,13 +205,44 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
 
     m0 = jnp.full((b, kh, g, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kh, g, sq), jnp.float32)
-    a0 = jnp.zeros((b, kh, g, sq, d), jnp.float32)
+    a0 = jnp.zeros((b, kh, g, sq, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), jnp.arange(nkc)))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
-    o = jnp.moveaxis(o, 3, 1).reshape(b, sq, h, d)   # [B,K,G,Sq,D] -> [B,Sq,H,D]
+    o = jnp.moveaxis(o, 3, 1).reshape(b, sq, h, dv)  # [B,K,G,Sq,D] -> [B,Sq,H,D]
     return o.astype(q.dtype)
+
+
+def _causal_query_blocks(q, k, v, *, block: int):
+    """Causal self-attention one block of `block` query rows at a time, each
+    against the keys up to its last row (about half the score work of a
+    full square), every block rematerialized: the backward keeps no score
+    block, where a KV-chunk scan keeps every chunk's f32 probabilities
+    (4 GB a layer at 4 x 4096 tokens of 16 heads). Products in the inputs'
+    dtype with f32 accumulation, an f32 softmax."""
+    b, s, h, d = q.shape
+    g = h // k.shape[2]
+    if g > 1:   # grouped kv heads: each query head gets its group's k, v
+        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    scale = 1.0 / math.sqrt(d)
+
+    def one(qb, kb, vb, q0):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
+                        preferred_element_type=jnp.float32) * scale
+        qpos = q0 + jnp.arange(qb.shape[1])[:, None]
+        sc = jnp.where(jnp.arange(kb.shape[1])[None, :] <= qpos, sc, NEG_INF)
+        p = jax.nn.softmax(sc, axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb,
+                       preferred_element_type=jnp.float32)
+        return o.astype(q.dtype)
+
+    one = jax.checkpoint(one, static_argnums=(3,))
+    outs = []
+    for q0 in range(0, s, block):
+        q1 = min(q0 + block, s)
+        outs.append(one(q[:, q0:q1], k[:, :q1], v[:, :q1], q0))
+    return jnp.concatenate(outs, axis=1)
 
 
 # ---------------------------------------------------------------------------

@@ -113,8 +113,8 @@ def gated_rmsnorm(p, x, gate, eps=1e-5):
 # MLP
 # ---------------------------------------------------------------------------
 
-def mlp_defs(cfg):
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_defs(cfg, f: Optional[int] = None):
+    d, f = cfg.d_model, f or cfg.d_ff
     scale_out = 0.02 / math.sqrt(2 * cfg.num_layers)
     if cfg.mlp_act in ("swiglu", "geglu"):
         defs = {"w_gate": ParamDef((d, f), ("d_model", "ff")),

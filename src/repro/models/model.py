@@ -49,6 +49,11 @@ class Model:
         defs = {"embed": embed_defs(cfg),
                 "decoder": tr.decoder_defs(cfg),
                 "final_norm": norm_defs(cfg, cfg.d_model)}
+        lead = tr.lead_defs(cfg)
+        if lead is not None:
+            # outside "decoder": the stacks' leaves are named per layer of
+            # their leading axis, which a one-layer stack would collide with
+            defs["lead"] = lead
         if cfg.is_encdec:
             defs["encoder"] = tr.encoder_defs(cfg)
         return defs
@@ -148,6 +153,15 @@ class Model:
         layer-streaming executor (host-resident params swapped in per layer).
         grad_hooks: per-stack-group DDL reduce-as-you-go hooks (overlapped
         backward — see core/ddl/overlap.py)."""
+        logits, aux, _ = self._forward(params, batch, policy=policy,
+                                       no_remat=no_remat, stream=stream,
+                                       grad_hooks=grad_hooks)
+        return logits, aux
+
+    def _forward(self, params, batch, *, policy=None, no_remat=False,
+                 stream=None, grad_hooks=None, router_bias=None):
+        """-> (logits, aux_loss, expert statistics of the stack: see
+        transformer.apply_decoder)."""
         cfg = self.cfg
         params = stream_in_unscanned(params, stream)
         x = self._embed_in(params, batch)
@@ -158,25 +172,41 @@ class Model:
                 cfg.encoder_seq, cfg.d_model).astype(x.dtype)[None]
             ctx["enc_out"] = tr.apply_encoder(cfg, params["encoder"], enc, ctx)
             x = x + sinusoidal_positions(seq, cfg.d_model).astype(x.dtype)[None]
-        x, aux = tr.apply_decoder(cfg, params["decoder"], x, ctx,
-                                  policy=policy, no_remat=no_remat,
-                                  unroll=self.unroll, stream=stream,
-                                  grad_hooks=grad_hooks)
+        if "lead" in params:     # a dense layer: no balance loss
+            x, _ = tr.apply_lead(cfg, params["lead"], x, ctx, policy=policy,
+                                 no_remat=no_remat)
+        x, aux, stats = tr.apply_decoder(
+            cfg, params["decoder"], x, ctx, policy=policy, no_remat=no_remat,
+            unroll=self.unroll, stream=stream, grad_hooks=grad_hooks,
+            router_bias=router_bias)
         x = apply_norm(cfg, params["final_norm"], x)
-        return lm_logits(cfg, params["embed"], x), aux
+        return lm_logits(cfg, params["embed"], x), aux, stats
 
     def loss(self, params, batch, *, policy=None, no_remat=False,
-             aux_weight: float = 0.01, stream=None, grad_hooks=None):
-        logits, aux = self.forward(params, batch, policy=policy,
-                                   no_remat=no_remat, stream=stream,
-                                   grad_hooks=grad_hooks)
+             stream=None, grad_hooks=None, router_bias=None):
+        """-> (ce + router_aux_weight * balance loss, metrics). With a
+        selection bias the metrics also hold `moe_picks` (picks per expert,
+        [stack layers, E]) and `moe_held_rows` (rows routed to the held
+        experts, all layers)."""
+        logits, aux, stats = self._forward(
+            params, batch, policy=policy, no_remat=no_remat, stream=stream,
+            grad_hooks=grad_hooks, router_bias=router_bias)
         ce = cross_entropy(logits, batch["labels"])
-        return ce + aux_weight * aux, {"ce": ce, "aux": aux}
+        metrics = {"ce": ce, "aux": aux}
+        if stats is not None:
+            metrics["moe_picks"] = stats[0]
+            metrics["moe_held_rows"] = stats[1].sum().astype(jnp.float32)
+        return ce + self.cfg.router_aux_weight * aux, metrics
 
     # ---- serving ----------------------------------------------------------
+    def _check_servable(self):
+        if self.cfg.is_mla:
+            raise NotImplementedError(tr.MLA_SERVING)
+
     def prefill(self, params, batch, cache_len: Optional[int] = None,
                 stream=None):
         """-> (last-token logits [B,V], cache)."""
+        self._check_servable()
         cfg = self.cfg
         params = stream_in_unscanned(params, stream)
         x = self._embed_in(params, batch)
@@ -203,6 +233,7 @@ class Model:
         after this chunk (tail rows past it are padding). -> (chunk logits
         [B,C,V], cache). Bitwise-equal to whole-prompt prefill per valid row
         (see apply_layer_prefill_chunk)."""
+        self._check_servable()
         cfg = self.cfg
         params = stream_in_unscanned(params, stream)
         x = self._embed_in(params, batch)
@@ -224,6 +255,7 @@ class Model:
         and `page_size` must be the arena's page length; the table rides
         through unchanged so the jitted step can donate it in place.
         -> (logits [B,V], new_cache)."""
+        self._check_servable()
         cfg = self.cfg
         params = stream_in_unscanned(params, stream)
         x = self._embed_in(params, batch, decode=True)
@@ -252,6 +284,7 @@ class Model:
     def decode_step(self, params, cache, batch, pos, stream=None):
         """batch: {"tokens" [B,1]} (or vlm embeds); pos: scalar int32.
         -> (logits [B,V], new_cache)."""
+        self._check_servable()
         cfg = self.cfg
         params = stream_in_unscanned(params, stream)
         x = self._embed_in(params, batch, decode=True)

@@ -96,6 +96,22 @@ def spec(*logical_axes: Optional[str], mesh: Optional[Mesh] = None,
     return P(*parts)
 
 
+def split_axes(logical_axis: str, dim: int) -> tuple:
+    """The active mesh's axes that split a dimension of size `dim` named
+    `logical_axis` (as `prune_spec` would keep them); () without a mesh,
+    where the split is one way or where it does not divide `dim`."""
+    env = _get_env()
+    if env is None or env[0] is None:
+        return ()
+    mesh, rules = env
+    axes = tuple(a for a in rules.get(logical_axis, ())
+                 if a in mesh.axis_names)
+    f = 1
+    for a in axes:
+        f *= mesh.shape[a]
+    return axes if f > 1 and dim % f == 0 else ()
+
+
 def named_sharding(mesh: Mesh, *logical_axes, memory_kind: Optional[str] = None,
                    rules: Optional[dict] = None) -> NamedSharding:
     s = NamedSharding(mesh, spec(*logical_axes, mesh=mesh, rules=rules))

@@ -18,14 +18,17 @@ from repro.models import attention as attn_mod
 from repro.models import kvquant
 from repro.models import paging
 from repro.models.attention import (attention_defs, project_qkv, out_proj,
-                                    decode_attention)
+                                    decode_attention, mla_defs, project_mla)
 from repro.models.layers import (ParamDef, apply_mlp, apply_norm, mlp_defs,
                                  norm_defs, apply_rope, apply_mrope)
-from repro.models.moe import moe_defs, apply_moe
+from repro.models.moe import moe_defs, apply_moe, shared_defs
 from repro.models.rglru import (rglru_defs, apply_rglru, decode_rglru,
                                 rglru_cache_defs)
 from repro.models.sharding import constrain
 from repro.models.ssm import (ssm_defs, apply_ssm, decode_ssm, ssm_cache_defs)
+
+MLA_SERVING = ("latent attention (MLA) trains only: serving needs a latent KV "
+               "cache, which is not implemented")
 
 # ---------------------------------------------------------------------------
 # Stack planning
@@ -42,7 +45,7 @@ def stack_plan(cfg: ModelConfig):
         if rem:
             plan.append(("unroll", tuple(rem)))
         return plan
-    return [("scan", (kinds[0],), cfg.num_layers)]
+    return [("scan", (kinds[0],), len(kinds))]
 
 
 def _stack(defs, n: int):
@@ -54,13 +57,25 @@ def _stack(defs, n: int):
         defs, is_leaf=is_def)
 
 
-def layer_defs(cfg: ModelConfig, kind: str):
-    if kind in ("attn", "local_attn", "enc_attn"):
-        d = {"ln1": norm_defs(cfg, cfg.d_model),
-             "attn": attention_defs(cfg),
-             "ln2": norm_defs(cfg, cfg.d_model),
-             "ffn": moe_defs(cfg) if cfg.num_experts else mlp_defs(cfg)}
-        return d
+def _ffn_defs(cfg: ModelConfig, dense: bool = False):
+    """The layer's FFN group(s): experts (with their shared experts) or an
+    MLP; `dense`: a leading dense layer's MLP."""
+    if dense:
+        return {"ffn": mlp_defs(cfg, cfg.dense_d_ff)}
+    if not cfg.num_experts:
+        return {"ffn": mlp_defs(cfg)}
+    out = {"ffn": moe_defs(cfg)}
+    if cfg.shared_experts:
+        out["shared"] = shared_defs(cfg)
+    return out
+
+
+def layer_defs(cfg: ModelConfig, kind: str, dense: bool = False):
+    if kind in ("attn", "local_attn", "enc_attn", "mla"):
+        return {"ln1": norm_defs(cfg, cfg.d_model),
+                "attn": mla_defs(cfg) if kind == "mla" else attention_defs(cfg),
+                "ln2": norm_defs(cfg, cfg.d_model),
+                **_ffn_defs(cfg, dense)}
     if kind == "xattn":  # whisper decoder layer: self + cross + mlp
         return {"ln1": norm_defs(cfg, cfg.d_model),
                 "attn": attention_defs(cfg),
@@ -76,6 +91,16 @@ def layer_defs(cfg: ModelConfig, kind: str):
                 "ln2": norm_defs(cfg, cfg.d_model),
                 "ffn": moe_defs(cfg) if cfg.num_experts else mlp_defs(cfg)}
     raise ValueError(kind)
+
+
+def lead_defs(cfg: ModelConfig):
+    """The leading dense layer, kept outside the scanned stack (its FFN
+    differs from the stack's); None when the model has none."""
+    if not cfg.first_dense_layers:
+        return None
+    if cfg.first_dense_layers != 1:
+        raise NotImplementedError("one leading dense layer at most")
+    return layer_defs(cfg, cfg.layer_kinds()[0], dense=True)
 
 
 def decoder_defs(cfg: ModelConfig):
@@ -114,11 +139,19 @@ def _rope_qk(cfg, q, k, ctx):
     return q, k
 
 
-def _ffn(cfg, p, x):
+def _ffn(cfg, p, x, ctx=None):
+    """-> (x, aux_loss). An expert layer reads its selection bias from
+    ctx["router_bias"] and appends (picks per expert, held rows) to
+    ctx["moe_stats"] when the context has them."""
     h = apply_norm(cfg, p.get("ln2", {}), x)
     h = tag(constrain(h, "batch", "seq_resid", None), "mlp_norm")
-    if cfg.num_experts:
-        y, aux = apply_moe(cfg, p["ffn"], h)
+    if "router" in p["ffn"]:
+        ctx = ctx or {}
+        y, aux, picks, rows = apply_moe(cfg, p["ffn"], h,
+                                        ctx.get("router_bias"),
+                                        p.get("shared"))
+        if "moe_stats" in ctx:
+            ctx["moe_stats"].append((picks, rows))
     else:
         y, aux = apply_mlp(cfg, p["ffn"], h), jnp.float32(0.0)
     return x + y, aux
@@ -127,6 +160,16 @@ def _ffn(cfg, p, x):
 def apply_layer(cfg, kind, p, x, ctx):
     """-> (x, aux_loss)."""
     x = tag(constrain(x, "batch", "seq_resid", None), "resid")
+    if kind == "mla":
+        with jax.named_scope("attn.mla"):
+            h = tag(apply_norm(cfg, p.get("ln1", {}), x), "attn_norm")
+            q, k, v = project_mla(cfg, p["attn"], h, ctx["positions"])
+            o = attn_mod.attention(q, k, v, causal=True,
+                                   impl=ctx["attn_impl"],
+                                   chunk=ctx["attn_chunk"])
+            o = tag(constrain(o, "batch", "seq", "heads", None), "attn_out")
+            x = x + out_proj(cfg, p["attn"], o)
+        return _ffn(cfg, p, x, ctx)
     if kind in ("attn", "local_attn", "enc_attn"):
         h = apply_norm(cfg, p.get("ln1", {}), x)
         h = tag(h, "attn_norm")
@@ -170,8 +213,29 @@ def _stream_depth(stream, n_iter: int) -> int:
     return d if n_iter % d == 0 else 1
 
 
+def _layer_ctx(ctx, bias, i: int):
+    """The context of layer i of a scan iteration: its selection bias and a
+    list for its expert statistics, when the model routes over experts."""
+    if bias is None:
+        return ctx
+    return {**ctx, "router_bias": bias[i], "moe_stats": []}
+
+
+def _per_iter(bias, n_iter: int):
+    """[layers, E] selection bias -> [n_iter, layers per iteration, E]."""
+    return None if bias is None else bias.reshape(n_iter, -1, bias.shape[-1])
+
+
+def _stack_stats(stats):
+    """[(picks [E], rows)] of one iteration's layers -> (picks [n, E],
+    rows [n]), or None."""
+    if not stats:
+        return None
+    return (jnp.stack([p for p, _ in stats]), jnp.stack([r for _, r in stats]))
+
+
 def _scan_streamed(cfg, stack, carry, ctx, pattern, n_iter, *, policy,
-                   no_remat, stream, grad_hook=None):
+                   no_remat, stream, grad_hook=None, bias=None):
     """Layer-streaming executor for one scan group (the LMS swap, executed).
 
     The stacked group params arrive host-resident (jit in_shardings carry the
@@ -196,8 +260,10 @@ def _scan_streamed(cfg, stack, carry, ctx, pattern, n_iter, *, policy,
     grouped = compat.tree.map(
         lambda t: t.reshape((n_iter // d, d) + t.shape[1:]), stack)
 
-    def body(c, lp_group, _pattern=pattern, _d=d, _hook=grad_hook):
+    def body(c, xs, _pattern=pattern, _d=d, _hook=grad_hook):
         h, a = c
+        lp_group, bias = xs
+        stats = []
         # swap-in first, compute second: the fetches are independent of the
         # compute below, so copy k+1 overlaps compute k
         bufs = [stream_layer_to_device(compat.tree.map(lambda t: t[k], lp_group))
@@ -206,18 +272,23 @@ def _scan_streamed(cfg, stack, carry, ctx, pattern, n_iter, *, policy,
             bufs = [_hook(b) for b in bufs]
         for k in range(_d):
             for i, kname in enumerate(_pattern):
-                h, da = apply_layer(cfg, kname, bufs[k][f"{kname}_{i}"], h, ctx)
+                lctx = _layer_ctx(ctx, bias, k * len(_pattern) + i)
+                h, da = apply_layer(cfg, kname, bufs[k][f"{kname}_{i}"], h,
+                                    lctx)
                 a = a + da
-        return (h, a), None
+                stats.extend(lctx.get("moe_stats", ()))
+        return (h, a), _stack_stats(stats)
 
     if not no_remat:
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-    return jax.lax.scan(body, carry, grouped)[0]
+    return jax.lax.scan(body, carry, (grouped, _per_iter(bias, n_iter // d)))
 
 
 def apply_decoder(cfg, params, x, ctx, *, policy=None, no_remat=False,
-                  unroll: bool = False, stream=None, grad_hooks=None):
-    """-> (x, aux_loss). Scans pattern groups with optional remat policy.
+                  unroll: bool = False, stream=None, grad_hooks=None,
+                  router_bias=None):
+    """-> (x, aux_loss, expert statistics). Scans pattern groups with
+    optional remat policy.
     unroll=True fully unrolls the layer scan — used by the dry-run so
     compiled.cost_analysis() counts every layer (XLA tallies a while-loop
     body once, ignoring the trip count). stream: a SwapSchedule whose
@@ -225,8 +296,12 @@ def apply_decoder(cfg, params, x, ctx, *, policy=None, no_remat=False,
     executor (host-resident params, per-layer double-buffered swap-in).
     grad_hooks: {stack group name -> reduce-as-you-go hook} — the DDL
     overlapped backward (per-layer gradient reduction issued inside the
-    scan's backward sweep instead of a post-hoc tree pass)."""
+    scan's backward sweep instead of a post-hoc tree pass).
+    router_bias: [stack layers, E] selection bias of an expert stack; the
+    statistics are then (picks per expert [stack layers, E], rows routed
+    to the held experts [stack layers]), else None."""
     aux = jnp.float32(0.0)
+    stats = None
     for gi, entry in enumerate(stack_plan(cfg)):
         if entry[0] == "scan":
             _, pattern, n_iter = entry
@@ -238,32 +313,59 @@ def apply_decoder(cfg, params, x, ctx, *, policy=None, no_remat=False,
             # as transpose(jvp(train.layers))
             if stream is not None and not unroll:
                 with jax.named_scope("train.layers"):
-                    x, aux = _scan_streamed(cfg, stack, (x, aux), ctx,
-                                            pattern, n_iter, policy=policy,
-                                            no_remat=no_remat, stream=stream,
-                                            grad_hook=hook)
+                    (x, aux), st = _scan_streamed(
+                        cfg, stack, (x, aux), ctx, pattern, n_iter,
+                        policy=policy, no_remat=no_remat, stream=stream,
+                        grad_hook=hook, bias=router_bias)
+                stats = _flat_stats(st)
                 continue
 
-            def body(carry, lp, _pattern=pattern, _hook=hook):
+            def body(carry, xs, _pattern=pattern, _hook=hook):
                 h, a = carry
+                lp, b = xs
                 if _hook is not None:
                     lp = _hook(lp)
+                st = []
                 for i, k in enumerate(_pattern):
-                    h, da = apply_layer(cfg, k, lp[f"{k}_{i}"], h, ctx)
+                    lctx = _layer_ctx(ctx, b, i)
+                    h, da = apply_layer(cfg, k, lp[f"{k}_{i}"], h, lctx)
                     a = a + da
-                return (h, a), None
+                    st.extend(lctx.get("moe_stats", ()))
+                return (h, a), _stack_stats(st)
 
             if not no_remat:
                 body = jax.checkpoint(body, policy=policy, prevent_cse=False)
             with jax.named_scope("train.layers"):
-                (x, aux), _ = jax.lax.scan(body, (x, aux), stack,
-                                           unroll=n_iter if unroll else 1)
+                (x, aux), st = jax.lax.scan(
+                    body, (x, aux), (stack, _per_iter(router_bias, n_iter)),
+                    unroll=n_iter if unroll else 1)
+            stats = _flat_stats(st)
         else:
             _, rem = entry
             for i, k in enumerate(rem):
                 x, da = apply_layer(cfg, k, params[f"rem{gi}"][f"layer{i}_{k}"], x, ctx)
                 aux = aux + da
-    return x, aux
+    return x, aux, stats
+
+
+def _flat_stats(st):
+    """Scanned statistics [iterations, layers per iteration, ...] -> one
+    row per layer, or None."""
+    if st is None:
+        return None
+    picks, rows = st
+    return picks.reshape(-1, picks.shape[-1]), rows.reshape(-1)
+
+
+def apply_lead(cfg, p, x, ctx, *, policy=None, no_remat=False):
+    """The leading dense layer, rematerialized as a scanned layer is."""
+    def layer(p_, x_):
+        return apply_layer(cfg, cfg.layer_kinds()[0], p_, x_, ctx)
+
+    if not no_remat:
+        layer = jax.checkpoint(layer, policy=policy, prevent_cse=False)
+    with jax.named_scope("train.lead"):
+        return layer(p, x)
 
 
 def apply_encoder(cfg, params, x, ctx):
@@ -307,6 +409,8 @@ def layer_cache_defs(cfg, kind, batch: int, cache_len: int):
         return ssm_cache_defs(cfg, batch)
     if kind == "rglru":
         return rglru_cache_defs(cfg, batch)
+    if kind == "mla":
+        raise NotImplementedError(MLA_SERVING)
     raise ValueError(kind)
 
 

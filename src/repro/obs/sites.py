@@ -25,6 +25,8 @@ SITE_PREFIXES = frozenset({
     "telemetry",  # obs/telemetry.py: loss-spike alerts
     "bench",      # benchmarks
     "data",       # data loading
+    "moe",        # models/moe.py + kernels/grouped_matmul: routing, experts
+    "attn",       # models/attention.py: attention variants (latent: attn.mla)
     "obs",        # the obs subsystem itself (self-metrics, test fixtures)
     "test",       # test-only sites
 })

@@ -69,6 +69,7 @@ class ServeEngine:
                  injector: Optional[FaultInjector] = None,
                  obs: Optional[Obs] = None):
         cfg = model.cfg
+        model._check_servable()
         self.model, self.cfg, self.mesh = model, cfg, mesh
         self.slots, self.max_len = slots, max_len
         self.temperature, self.top_k = temperature, top_k

@@ -72,6 +72,26 @@ class TrainState(NamedTuple):
     step: jnp.ndarray
     params: Any
     opt: Any
+    # per-layer expert selection bias [stack layers, E] f32 of a model with
+    # router_bias_rate > 0: step state outside the params, moved by load
+    # (update_router_bias), never by the optimizer; None otherwise
+    router_bias: Any = None
+
+
+def init_router_bias(cfg):
+    """Zeros [stack layers, E] for a model that balances by selection bias,
+    else None."""
+    if not (cfg.num_experts and cfg.router_bias_rate):
+        return None
+    return jnp.zeros((cfg.stack_layers, cfg.num_experts), jnp.float32)
+
+
+def update_router_bias(cfg, bias, picks):
+    """DeepSeek-V3's auxiliary-loss-free balancing: after each step,
+    b_i += rate * sign(mean load - load_i), loads counted over the step's
+    tokens. bias, picks [stack layers, E]."""
+    mean = jnp.mean(picks, axis=-1, keepdims=True)
+    return bias + cfg.router_bias_rate * jnp.sign(mean - picks)
 
 
 @dataclass(frozen=True)
@@ -543,14 +563,16 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
         sspec = ddl_overlap.shard_spec(pshapes, data_size, stacked)
 
     inner_rules = rules_without(dpa, rules=rules)
+    biased = init_router_bias(cfg) is not None
 
-    def loss_fn(params, batch):
+    def loss_fn(params, batch, bias):
         with sharding_env(mesh, rules=inner_rules):
             loss, metrics = model.loss(params, batch, policy=policy,
-                                       stream=stream, grad_hooks=hooks)
+                                       stream=stream, grad_hooks=hooks,
+                                       router_bias=bias)
         return loss, metrics
 
-    def grads_of(params, batch):
+    def grads_of(params, batch, bias):
         """-> (loss, metrics, grads). `metrics` is the model's REAL aux
         metrics ({"ce", "aux"}: cross-entropy and the MoE load-balance
         loss), microbatch-averaged — not fabricated placeholders. With
@@ -559,12 +581,23 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
         shards and all-gathered once)."""
         if m > 1:
             mb_batch = _microbatch_split(batch, m)
-            zero_metrics = {"ce": jnp.float32(0.0), "aux": jnp.float32(0.0)}
+            zero_metrics = compat.tree.map(
+                lambda x: jnp.zeros(x.shape, x.dtype),
+                jax.eval_shape(loss_fn, params,
+                               compat.tree.map(lambda x: x[0], mb_batch),
+                               bias)[1])
+
+            def mean_metrics(mets):
+                """ce and aux averaged over the microbatches; the expert
+                counts stay the step's totals."""
+                return {k: v / m if k in ("ce", "aux") else v
+                        for k, v in mets.items()}
+
             if overlap:
                 def micro(carry, mb):
                     acc, l_acc, m_acc = carry
                     (l, mets), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                        params, mb)
+                        params, mb, bias)
                     loc = ddl_overlap.collect_local_shards(
                         g, sspec, stacked, data_axis="data",
                         pod_axis=pod_axis, mean_over=mean_over,
@@ -577,12 +610,12 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
                     micro, (acc0, jnp.float32(0.0), zero_metrics), mb_batch)
                 g = ddl_overlap.allgather_local_shards(loc / m, sspec,
                                                        data_axis="data")
-                return l / m, compat.tree.map(lambda x: x / m, mets), g
+                return l / m, mean_metrics(mets), g
 
             def micro(carry, mb):
                 g_acc, l_acc, m_acc = carry
                 (l, mets), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, mb)
+                    params, mb, bias)
                 m_acc = compat.tree.map(jnp.add, m_acc, mets)
                 return (compat.tree.map(jnp.add, g_acc, g), l_acc + l,
                         m_acc), None
@@ -591,13 +624,14 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
             (g, l, mets), _ = jax.lax.scan(
                 micro, (zero, jnp.float32(0.0), zero_metrics), mb_batch)
             g = compat.tree.map(lambda x: x / m, g)
-            return l / m, compat.tree.map(lambda x: x / m, mets), g
-        (l, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+            return l / m, mean_metrics(mets), g
+        (l, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch, bias)
         return l, metrics, g
 
     def per_replica(state: TrainState, batch):
         params, opt_state = state.params, state.opt
-        loss, metrics, grads = grads_of(params, batch)
+        loss, metrics, grads = grads_of(params, batch, state.router_bias)
         if not overlap:
             # DDL: post-hoc topology-aware reduction over the DP axes
             grads, _ = ddl_reduce_tree(grads, ddl, data_axis="data",
@@ -648,18 +682,30 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
         out_metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr,
                        "ce": jax.lax.pmean(metrics["ce"], dpa),
                        "aux": jax.lax.pmean(metrics["aux"], dpa)}
-        return TrainState(state.step + 1, new_params, new_opt), out_metrics
+        bias = state.router_bias
+        if biased:
+            # loads summed over the data axis; the held-rows count is one
+            # chip's (the mean over the data axis), all layers, per step
+            bias = update_router_bias(
+                cfg, bias, jax.lax.psum(metrics["moe_picks"], dpa))
+            out_metrics["moe_held_rows"] = jax.lax.pmean(
+                metrics["moe_held_rows"], dpa)
+        return (TrainState(state.step + 1, new_params, new_opt, bias),
+                out_metrics)
 
     # shard_map: manual over DP axes only; GSPMD handles `model`
     replicated = compat.tree.map(lambda _: P(), pspecs)
     opt_replicated = _opt_specs_like(opt_init, replicated)
-    state_specs_manual = TrainState(P(), replicated, opt_replicated)
+    state_specs_manual = TrainState(P(), replicated, opt_replicated,
+                                    P() if biased else None)
     _, bshards = model.input_specs(tcfg.shape, mesh)
     # inputs are only DP-sharded, so their physical specs double as the
     # manual specs for the shard_map over the DP axes
     batch_manual = bshards
     metric_specs = {"loss": P(), "grad_norm": P(), "lr": P(),
                     "ce": P(), "aux": P()}
+    if biased:
+        metric_specs["moe_held_rows"] = P()
 
     step_sm = compat.shard_map(
         per_replica, mesh=mesh,
@@ -709,13 +755,17 @@ def make_state_shardings(model: Model, tcfg: TrainConfig, mesh,
     # the optimizer's step counter is read by compute (bias corrections)
     # and stays on device whatever the state's residency
     opt_sh = shard(ospecs, o_kind)._replace(step=NamedSharding(mesh, P()))
-    return TrainState(step=NamedSharding(mesh, P()), params=params_sh, opt=opt_sh)
+    bias_sh = (NamedSharding(mesh, P())
+               if init_router_bias(model.cfg) is not None else None)
+    return TrainState(step=NamedSharding(mesh, P()), params=params_sh,
+                      opt=opt_sh, router_bias=bias_sh)
 
 
 def init_train_state(model: Model, tcfg: TrainConfig, rng) -> TrainState:
     params = model.init(rng)
     opt_init, _ = OPTIMIZERS[tcfg.optimizer]
-    return TrainState(jnp.zeros((), jnp.int32), params, opt_init(params))
+    return TrainState(jnp.zeros((), jnp.int32), params, opt_init(params),
+                      init_router_bias(model.cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -748,6 +798,9 @@ def build_zero1_train_step(model: Model, tcfg: TrainConfig, mesh,
     stream = _param_stream(plan)
     sched = SCHEDULES["warmup_cosine"]
 
+    if init_router_bias(cfg) is not None:
+        raise NotImplementedError("selection-bias routing trains with the "
+                                  "allreduce step (its bias is step state)")
     shapes, pspecs = model.abstract_params(mesh)
     # the flat optimizer-state LAYOUT must match init_zero1_state, which
     # sees neither `plan` nor a builder arg — zero1 overlap resolution is

@@ -124,7 +124,9 @@ class Trainer:
             opt = AdamState(np.asarray(o["step"]), o["mu"], o["nu"], o["master"])
         else:
             opt = SGDState(np.asarray(o["step"]), o["momentum"])
-        return TrainState(step, d["params"], opt)
+        bias = d.get("router_bias")
+        return TrainState(step, d["params"], opt,
+                          None if bias is None else np.asarray(bias))
 
     def _make_batch(self) -> Dict[str, jnp.ndarray]:
         cfg = self.tcfg.model
@@ -176,6 +178,11 @@ class Trainer:
                        # ce = cross-entropy, aux = MoE balance loss
                        "ce": float(metrics["ce"]),
                        "aux": float(metrics["aux"])}
+                if "moe_held_rows" in metrics:
+                    # rows routed to the held experts this step
+                    row["moe_held_rows"] = float(metrics["moe_held_rows"])
+                    self.obs.registry.gauge("moe.held_rows").set(
+                        row["moe_held_rows"])
                 metrics_hist.append(row)
                 series.append(row)
                 if on_step:
@@ -247,5 +254,7 @@ class Trainer:
         else:
             payload = {"step": state.step, "params": state.params,
                        "opt": dict(state.opt._asdict())}
+            if state.router_bias is not None:
+                payload["router_bias"] = state.router_bias
         self.ckpt.save(step, payload, process=self.process,
                        extra={"data_state": self.loader.snapshot()})

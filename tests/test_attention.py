@@ -37,6 +37,32 @@ def test_blockwise_vs_naive(case):
                                atol=3e-5, rtol=3e-5)
 
 
+@pytest.mark.parametrize("dv,chunk", [(16, 16), (48, 32), (24, 64)])
+def test_blockwise_v_width_differs_from_qk(dv, chunk, monkeypatch):
+    """Latent attention's v is narrower than q/k (128 against 192): the
+    KV-chunk scan and the causal query blocks (taken once the probabilities
+    outgrow the scan's limit) both match the naive oracle, forward and
+    gradients."""
+    import repro.models.attention as attn_mod
+    q, k, _ = _qkv(2, 64, 64, 4, 2, 32, seed=dv)
+    v = jnp.asarray(np.random.default_rng(dv + 1).standard_normal(
+        (2, 64, 2, dv)), jnp.float32)
+    ref = naive_attention(q, k, v, causal=True)
+    assert ref.shape == (2, 64, 4, dv)
+    grad = lambda f: jax.grad(lambda q_, k_, v_: jnp.sum(jnp.sin(
+        f(q_, k_, v_))), argnums=(0, 1, 2))(q, k, v)
+    want = grad(lambda *a: naive_attention(*a, causal=True))
+    for limit in (attn_mod.KV_SCAN_MAX_SCORE_BYTES, 0):
+        monkeypatch.setattr(attn_mod, "KV_SCAN_MAX_SCORE_BYTES", limit)
+        out = blockwise_attention(q, k, v, causal=True, chunk=chunk)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=3e-5, rtol=3e-5)
+        for a, b in zip(grad(lambda *a: blockwise_attention(
+                *a, causal=True, chunk=chunk)), want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4)
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 3), st.integers(2, 6), st.integers(1, 2),
        st.sampled_from([8, 16]), st.integers(0, 2**31 - 1))

@@ -1,4 +1,4 @@
-"""Config registry: all 10 assigned architectures, parameter counts against
+"""Config registry: all 11 architectures, parameter counts against
 their published sizes, shape applicability rules."""
 import pytest
 
@@ -8,7 +8,7 @@ from repro.configs import ARCH_IDS, REGISTRY, get_config, get_smoke_config
 EXPECTED_ARCHS = {
     "qwen2.5-14b", "olmo-1b", "starcoder2-7b", "qwen2-72b", "mamba2-1.3b",
     "grok-1-314b", "qwen3-moe-235b-a22b", "recurrentgemma-9b", "qwen2-vl-2b",
-    "whisper-tiny",
+    "whisper-tiny", "moonlight-16b-a3b",
 }
 
 # published total param counts (tolerance: naming conventions vary on
@@ -24,6 +24,7 @@ PARAM_TARGETS = {
     "recurrentgemma-9b": (9.2e9, 0.30),
     "qwen2-vl-2b": (2.2e9, 0.35),
     "whisper-tiny": (39e6, 0.50),
+    "moonlight-16b-a3b": (15.96e9, 0.05),
 }
 
 
@@ -53,6 +54,20 @@ def test_moe_active_params():
     assert abs(active - 22e9) / 22e9 < 0.35, f"active {active:.3e} vs ~22e9"
     grok = get_config("grok-1-314b")
     assert grok.active_param_count() < grok.param_count() / 2
+    moon = get_config("moonlight-16b-a3b")      # "A3B": ~3B active
+    assert abs(moon.active_param_count() - 2.9e9) / 2.9e9 < 0.1
+
+
+def test_moonlight_chip_share_sizes():
+    """One chip's EP-8 share of 14 layers: the dense layer 83.0 M, 13 MoE
+    layers of 100.4 M each, embedding plus head 83.9 M; 1,472 M in all."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), num_layers=14,
+                              experts_held=8, vocab_size=20480)
+    parts = dict(cfg.param_breakdown())
+    assert cfg.stack_layers == 13 and cfg.layer_kinds() == ("mla",) * 13
+    assert parts["embed"] + parts["lm_head"] == 83_886_080
+    assert abs(cfg.param_count() - 1_472e6) < 0.5e6
 
 
 def test_long500k_applicability():

@@ -7,8 +7,9 @@ from repro import hw as hwlib
 from repro.config.base import (SHAPES, SINGLE_POD, MULTI_POD, LMSConfig,
                                ShapeConfig)
 from repro.configs import ARCH_IDS, get_config
-from repro.core.lms.planner import (activation_classes, plan_memory,
-                                    plan_to_policy, hbm_traffic_model)
+from repro.core.lms.planner import (activation_classes, layer_work_bytes,
+                                    plan_memory, plan_to_policy,
+                                    hbm_traffic_model)
 
 
 @pytest.mark.parametrize("arch", sorted(ARCH_IDS))
@@ -88,3 +89,23 @@ def test_remat_preferred_on_slow_link():
     fast_offloads = {k for k, v in fast.assignment.items() if v == "offload"}
     assert not slow_offloads, slow_offloads
     assert fast_offloads  # the fast link does swap
+
+
+@pytest.mark.parametrize("arch,priced", [
+    ("olmo-1b", False), ("qwen3-moe-235b-a22b", True), ("grok-1-314b", True),
+    ("moonlight-16b-a3b", True)])
+def test_layer_work_prices_dispatch_and_latent_scores(arch, priced):
+    """The dropless dispatch's [T*k] rows are priced for every MoE model,
+    latent attention's score block for MLA; a dense MHA layer adds
+    nothing (olmo-1b's plan is unchanged by the term)."""
+    from repro.config.base import MeshSpec
+    cfg = get_config(arch)
+    one_chip = MeshSpec((1, 1), ("data", "model"))
+    work = layer_work_bytes(cfg, ShapeConfig("t", "train", 4096, 4),
+                            one_chip)
+    assert (work > 0) == priced
+    if cfg.num_experts:
+        rows = 4 * 4096 * cfg.experts_per_token
+        assert work >= 2 * 4 * rows * cfg.d_model
+    if cfg.is_mla:
+        assert work >= 3 * 4 * 4 * cfg.num_heads * 512 * 4096

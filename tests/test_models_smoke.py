@@ -105,3 +105,19 @@ def test_ring_cache_local_attention():
     np.testing.assert_allclose(
         np.asarray(logits, np.float32),
         np.asarray(logits_full[:, total], np.float32), atol=0.15, rtol=0.1)
+
+
+def test_latent_attention_models_refuse_serving():
+    """MLA trains only: prefill, decode and the serve engine refuse it with
+    a clear error instead of building a cache it does not have."""
+    from repro.serve.engine import ServeEngine
+    cfg = get_smoke_config("moonlight-16b-a3b")
+    model = Model(cfg, attn_impl="naive")
+    params = model.init(jax.random.key(0))
+    toks = jnp.ones((B, S), jnp.int32)
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.prefill(params, {"tokens": toks})
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        model.decode_step(params, {}, {"tokens": toks[:, :1]}, jnp.int32(0))
+    with pytest.raises(NotImplementedError, match="latent KV cache"):
+        ServeEngine(model, None, slots=2, max_len=32, params=params)

@@ -30,6 +30,7 @@ from repro.core.lms.planner import MemoryPlan, make_swap_schedule
 from repro.kernels.flash_attention.decode_kernel import (
     flash_decode_fwd, flash_decode_paged_fwd)
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.grouped_matmul.kernel import gmm_fwd
 from repro.kernels.quantize.kernel import dequantize_fwd, quantize_fwd
 from repro.kernels.rmsnorm.kernel import rmsnorm_fwd
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
@@ -119,6 +120,23 @@ def test_flash_decode_paged_compiles(one_chip, kv_dtype):
 def test_rmsnorm_compiles(one_chip):
     _compile(rmsnorm_fwd, one_chip, ((4096, OLMO.d_model), jnp.bfloat16),
              ((OLMO.d_model,), jnp.float32))
+
+
+@pytest.mark.parametrize("kn", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, kn):
+    """Moonlight's held experts (8 of 64, d 2048, expert width 1408): the
+    megablox gmm forward and its VJP (gmm for the rows, tgmm for the
+    weights) with the repo's tiling; 8192 rows sorted into 8 groups plus
+    the uncomputed rest."""
+    k, n = kn
+
+    def fwd_bwd(lhs, rhs, sizes):
+        out, vjp = jax.vjp(lambda a, b: gmm_fwd(a, b, sizes), lhs, rhs)
+        return out, vjp(out)
+
+    _compile(fwd_bwd, one_chip, ((8192, k), jnp.bfloat16),
+             ((8, k, n), jnp.bfloat16), ((9,), jnp.int32))
 
 
 def test_quantize_roundtrip_compiles(one_chip):
@@ -378,3 +396,40 @@ def test_overlapped_ddl_step_compiles_without_collective_fusions(topo):
     hlo = step.lower(state, batch).compile().as_text()
     assert "reduce-scatter(" in hlo
     assert "async_collective_fusion" not in hlo
+
+
+def test_moonlight_step_with_host_optimizer_compiles(one_chip, monkeypatch):
+    """Latent attention at its real head widths (q/k 192, v 128, latent 512,
+    rope 64), held experts through the grouped-matmul kernels (inside the
+    step's shard_map, manual over the axes the step leaves automatic), a
+    leading dense layer swept in row blocks, AdamW state in pinned host:
+    the TPU compiler takes it. Host arrays [d, 576] or [d, 16, 192] would
+    be refused here (lane-slice write-backs of their row blocks)."""
+    import repro.kernels.gates as gates
+    monkeypatch.setattr(compat, "host_memory_kind", lambda: "pinned_host")
+    monkeypatch.setattr(gates, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(
+        get_config("moonlight-16b-a3b"), num_layers=3, d_model=2048,
+        experts_held=2, vocab_size=4096, dense_d_ff=1024)
+    model = Model(cfg)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    tcfg = TrainConfig(model=cfg, shape=ShapeConfig("s", "train", 512, 1),
+                       mesh=MeshSpec((1, 1), ("data", "model")))
+    residency = {"params": "device", "grads": "device",
+                 "optimizer": "host", "kvcache": "device"}
+    plan = MemoryPlan({}, residency, 1, 1, 1, 1, True,
+                      swap_schedule=make_swap_schedule(
+                          residency, cfg.num_layers, "train"))
+    step, state_sh, batch_sh = build_train_step(model, tcfg, mesh, plan=plan)
+    init = functools.partial(init_train_state, model, tcfg)
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(init, jax.random.key(0)), state_sh)
+    specs, _ = model.input_specs(tcfg.shape, mesh)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=batch_sh[k])
+             for k, v in specs.items()}
+    text = step.lower(state, batch).compile().as_text()
+    assert text.count("tpu_custom_call") >= 6      # gmm and tgmm
+    for scope in ("/attn.mla/", "/moe.experts/", "/lms.opt_sweep/"):
+        assert scope in text, scope
