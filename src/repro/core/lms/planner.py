@@ -451,8 +451,8 @@ def activation_classes(cfg: ModelConfig, shape: ShapeConfig,
         w = cfg.lru_width or d
         out.append(TensorClass("lru_h", bs2 * w // tp, 4.0 * b * s * w * w / tp))
     if cfg.num_experts:
-        # dropless dispatch: a row for every pick, of which the held
-        # experts' are computed
+        # dropless dispatch, priced at a row for every pick: an upper
+        # bound on the chunks of held picks the layer runs (models/moe.py)
         rows = b * s * cfg.experts_per_token
         held = rows * cfg.held // cfg.num_experts
         out.append(TensorClass("moe_hidden", rows * f * 2 // tp,
@@ -479,9 +479,10 @@ def layer_work_bytes(cfg: ModelConfig, shape: ShapeConfig,
     activations, for the layer kinds whose working set outgrows the largest
     activation class (0 for the others): latent attention's last query
     block (f32 scores, probabilities and their gradient, [B,H,chunk,S]
-    each) and the dropless expert dispatch's row buffers (the gathered rows
-    and the per-pick outputs, [T*k, d] each, bf16, and their gradients; its
-    hidden units are the `moe_hidden` class), whichever is larger."""
+    each) and the dropless expert dispatch's row buffers (priced at [T*k,
+    d] each for the gathered rows and the outputs, bf16, and their
+    gradients: an upper bound on its chunks of held picks; its hidden
+    units are the `moe_hidden` class), whichever is larger."""
     dp = _axis_size(mesh, "data") * _axis_size(mesh, "pod")
     tp = _axis_size(mesh, "model")
     b = max(shape.global_batch // dp, 1)

@@ -23,7 +23,8 @@ ACTIVATION_NAMES = (
     "qkv",          # projected q (k,v smaller w/ GQA)      [B,S,H,D]
     "attn_out",     # attention output pre-proj             [B,S,H,D]
     "mlp_hidden",   # MLP hidden                            [B,S,f]
-    "moe_hidden",   # gathered expert hidden                [E,C,f]
+    "moe_hidden",   # expert hidden, priced only: moe.py    [C,f]
+                    # recomputes it in its own backward
     "router_probs", # router softmax                        [B,S,E]
     "ssd_state",    # per-chunk SSD states                  [B,nc,H,P,N]
     "ssd_xz",       # ssm in-proj output                    [B,S,2*di]

@@ -3,6 +3,7 @@ abstract params + shardings for the dry-run, and per-arch `input_specs`.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -14,6 +15,7 @@ from repro.models.layers import (embed_defs, embed_tokens, lm_logits,
                                  cross_entropy, norm_defs, apply_norm,
                                  sinusoidal_positions, tree_init, tree_abstract,
                                  ParamDef)
+from repro.models.moe import chunk_rows
 from repro.models.sharding import constrain, prune_spec, spec as mkspec
 from repro.core.lms.offload import stream_layer_to_device
 
@@ -186,8 +188,9 @@ class Model:
              stream=None, grad_hooks=None, router_bias=None):
         """-> (ce + router_aux_weight * balance loss, metrics). With a
         selection bias the metrics also hold `moe_picks` (picks per expert,
-        [stack layers, E]) and `moe_held_rows` (rows routed to the held
-        experts, all layers)."""
+        [stack layers, E]), `moe_held_rows` (rows routed to the held
+        experts, all layers) and `moe_held_chunks` (the chunks of
+        `moe.chunk_rows` the layers ran them in, all layers)."""
         logits, aux, stats = self._forward(
             params, batch, policy=policy, no_remat=no_remat, stream=stream,
             grad_hooks=grad_hooks, router_bias=router_bias)
@@ -196,6 +199,9 @@ class Model:
         if stats is not None:
             metrics["moe_picks"] = stats[0]
             metrics["moe_held_rows"] = stats[1].sum().astype(jnp.float32)
+            c = chunk_rows(self.cfg, math.prod(logits.shape[:-1]))
+            metrics["moe_held_chunks"] = (-(-stats[1] // c)).sum().astype(
+                jnp.float32)
         return ce + self.cfg.router_aux_weight * aux, metrics
 
     # ---- serving ----------------------------------------------------------

@@ -684,12 +684,13 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
                        "aux": jax.lax.pmean(metrics["aux"], dpa)}
         bias = state.router_bias
         if biased:
-            # loads summed over the data axis; the held-rows count is one
-            # chip's (the mean over the data axis), all layers, per step
+            # loads summed over the data axis; the held rows and their
+            # chunks are one chip's (the mean over the data axis), all
+            # layers, per step
             bias = update_router_bias(
                 cfg, bias, jax.lax.psum(metrics["moe_picks"], dpa))
-            out_metrics["moe_held_rows"] = jax.lax.pmean(
-                metrics["moe_held_rows"], dpa)
+            for k in ("moe_held_rows", "moe_held_chunks"):
+                out_metrics[k] = jax.lax.pmean(metrics[k], dpa)
         return (TrainState(state.step + 1, new_params, new_opt, bias),
                 out_metrics)
 
@@ -705,7 +706,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
     metric_specs = {"loss": P(), "grad_norm": P(), "lr": P(),
                     "ce": P(), "aux": P()}
     if biased:
-        metric_specs["moe_held_rows"] = P()
+        metric_specs["moe_held_rows"] = metric_specs["moe_held_chunks"] = P()
 
     step_sm = compat.shard_map(
         per_replica, mesh=mesh,

@@ -179,10 +179,14 @@ class Trainer:
                        "ce": float(metrics["ce"]),
                        "aux": float(metrics["aux"])}
                 if "moe_held_rows" in metrics:
-                    # rows routed to the held experts this step
+                    # rows routed to the held experts this step, and the
+                    # chunks they ran in (one a layer unless one overflowed)
                     row["moe_held_rows"] = float(metrics["moe_held_rows"])
+                    row["moe_held_chunks"] = float(metrics["moe_held_chunks"])
                     self.obs.registry.gauge("moe.held_rows").set(
                         row["moe_held_rows"])
+                    self.obs.registry.gauge("moe.held_chunks").set(
+                        row["moe_held_chunks"])
                 metrics_hist.append(row)
                 series.append(row)
                 if on_step:

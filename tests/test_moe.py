@@ -13,7 +13,8 @@ from repro.configs import get_smoke_config
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.grouped_matmul.ref import gmm_ref
 from repro.models.layers import apply_mlp, tree_init
-from repro.models.moe import apply_moe, moe_defs, route, shared_defs
+from repro.models.moe import (apply_moe, chunk_rows, moe_defs, route,
+                              shared_defs)
 from repro.train.steps import update_router_bias
 from tests.util import run_py
 
@@ -82,13 +83,47 @@ def test_moe_grads_flow():
     assert float(jnp.abs(g["router"].astype(jnp.float32)).sum()) > 0
 
 
-@pytest.mark.parametrize("offset", [0, 4])
-def test_held_experts_forward_and_grads_match_dense_oracle(offset):
-    """Four of eight experts held, sigmoid routing with a bias: output and
-    every gradient equal the dense oracle's."""
-    cfg, params, x = _setup("moonlight-16b-a3b", experts_held=4,
-                            experts_offset=offset)
-    bias = jnp.linspace(-0.05, 0.05, cfg.num_experts)
+def _held_bias(cfg, push: float):
+    """A selection bias that favours the held experts by `push` (10: every
+    pick goes to them, when they are at least k)."""
+    lo, hi = cfg.experts_offset, cfg.experts_offset + cfg.held
+    small = np.random.default_rng(2).standard_normal(cfg.num_experts) * 5e-3
+    return jnp.asarray(small, jnp.float32).at[lo:hi].add(push)
+
+
+# (experts_held, experts_offset, push onto the held experts, chunks) with
+# 32 experts, k 3 and 512 tokens: C = 512 of the 1536 picks unless every
+# expert is held (C = T*k); a push of 10 sends all 1536 picks to 4 held
+# experts, three chunks
+HELD_CASES = {"one_chunk-offset0": (4, 0, 0.0, 1),
+              "one_chunk-offset28": (4, 28, 0.0, 1),
+              "overflow": (4, 12, 10.0, 3),
+              "whole_layer": (0, 0, 0.0, 1)}
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_held_experts_forward_and_grads_match_dense_oracle(case):
+    """Sigmoid routing with a selection bias, 512 tokens over 32 experts:
+    output and every gradient (x, the experts, the router) equal the dense
+    oracle's, in one chunk of held picks, in three when the bias sends
+    every pick to the held experts, and with every expert held (one chunk
+    of every pick)."""
+    held, offset, push, chunks = HELD_CASES[case]
+    cfg, params, _ = _setup("moonlight-16b-a3b", num_experts=32,
+                            experts_held=held, experts_offset=offset)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (2, 256, cfg.d_model)) * 0.5, jnp.float32)
+    bias = _held_bias(cfg, push)
+    tk = x.shape[0] * x.shape[1] * cfg.experts_per_token
+    c = chunk_rows(cfg, tk // cfg.experts_per_token)
+    assert (c == tk) == (held == 0)
+    rows = int(apply_moe(cfg, params, x, bias)[3])
+    assert -(-rows // c) == chunks and (push == 0 or rows == tk)
+    if chunks > 1:
+        # an expert's weight gradient is then a sum of one partial a chunk,
+        # each rounded to the weights' bf16 where the oracle rounds once:
+        # compare the two in f32, where that rounding is gone
+        params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
 
     def loss(fn, p, x_):
         return jnp.sum(jnp.sin(fn(p, x_)))
@@ -99,9 +134,53 @@ def test_held_experts_forward_and_grads_match_dense_oracle(offset):
                                np.asarray(oracle(params, x)), atol=2e-5)
     gp = jax.grad(lambda p, x_: loss(prog, p, x_), argnums=(0, 1))(params, x)
     go = jax.grad(lambda p, x_: loss(oracle, p, x_), argnums=(0, 1))(params, x)
+    assert float(jnp.abs(gp[0]["router"]).sum()) > 0
     for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(go)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
                                    rtol=1e-4)
+
+
+@pytest.mark.parametrize("push,chunks", [(0.0, 1), (10.0, 3)],
+                         ids=["one_chunk", "overflow"])
+def test_held_chunks_metric_sums_chunks_over_layers(push, chunks):
+    """`moe_held_chunks` is the sum over the MoE layers of ceil(held rows /
+    C): one a layer under balanced routing, three when every pick is
+    held."""
+    from repro.models.model import Model
+    cfg = override(get_smoke_config("moonlight-16b-a3b"), num_experts=32,
+                   experts_held=4)
+    model = Model(cfg, attn_impl="naive")
+    params = model.init(jax.random.key(0))
+    toks = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 257))
+    batch = {"tokens": jnp.asarray(toks[:, :-1], jnp.int32),
+             "labels": jnp.asarray(toks[:, 1:], jnp.int32)}
+    bias = jnp.stack([_held_bias(cfg, push)] * cfg.stack_layers)
+    _, mets = model.loss(params, batch, router_bias=bias)
+    rows = model._forward(params, batch, router_bias=bias)[2][1]
+    c = chunk_rows(cfg, 512)
+    assert c == 512 and rows.shape == (cfg.stack_layers,)
+    want = sum(-(-int(r) // c) for r in rows)
+    assert float(mets["moe_held_chunks"]) == want == chunks * cfg.stack_layers
+    assert float(mets["moe_held_rows"]) == float(rows.sum())
+
+
+def test_trainer_sets_the_held_chunks_gauge_at_a_flush(tmp_path):
+    """Every expert held at 128 tokens: one chunk of every pick a layer,
+    so the step's row and the `moe.held_chunks` gauge read the MoE
+    layers' count."""
+    from repro.config.base import LMSConfig, MeshSpec, ShapeConfig, TrainConfig
+    from repro.train.trainer import Trainer
+    cfg = get_smoke_config("moonlight-16b-a3b")
+    tr = Trainer(TrainConfig(
+        model=cfg, shape=ShapeConfig("t", "train", 32, 4),
+        mesh=MeshSpec((1, 1), ("data", "model")), lms=LMSConfig(enabled=True),
+        checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=0,
+        log_every=1), attn_impl="naive")
+    _, hist = tr.train(steps=2)
+    assert [r["moe_held_chunks"] for r in hist] == [cfg.stack_layers] * 2
+    assert tr.obs.registry.gauge("moe.held_chunks").value == cfg.stack_layers
+    assert tr.obs.registry.gauge("moe.held_rows").value == \
+        hist[-1]["moe_held_rows"] == 128 * 3 * cfg.stack_layers
 
 
 def test_chip_shares_add_up_to_the_uncut_layer():
@@ -229,3 +308,58 @@ def test_grouped_matmul_expert_parallel_matches_oracle():
     emulated CPU devices, Pallas interpreter."""
     out = run_py(_EXPERT_PARALLEL, devices=4)
     assert "seen [2, 2]" in out and out.rstrip().endswith("ok")
+
+
+_EXPERT_PARALLEL_MOE = r'''
+import os
+os.environ["REPRO_PALLAS_INTERPRET"] = "1"
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.config.base import override
+from repro.configs import get_smoke_config
+from repro.kernels.grouped_matmul import ops
+from repro.models.layers import tree_init
+from repro.models.moe import apply_moe, chunk_rows, moe_defs
+from repro.models.sharding import sharding_env
+from tests.test_moe import dense_oracle
+seen = []
+inner = ops.gmm_fwd
+def spy(lhs, rhs, gs, **kw):
+    seen.append((lhs.shape[0], rhs.shape[0]))
+    return inner(lhs, rhs, gs, **kw)
+ops.gmm_fwd = spy
+cfg = override(get_smoke_config("moonlight-16b-a3b"), num_experts=16,
+               experts_held=4, experts_offset=8)
+params = jax.tree.map(lambda a: a.astype(jnp.float32),
+                      tree_init(jax.random.key(0), moe_defs(cfg)))
+x = jnp.asarray(np.random.default_rng(1).standard_normal(
+    (2, 256, cfg.d_model)) * 0.5, jnp.float32)
+# every pick to the four held experts: 1536 rows, two chunks of 1024
+bias = jnp.zeros(cfg.num_experts).at[8:12].set(10.0)
+c = chunk_rows(cfg, 512)
+assert c == 1024 and int(apply_moe(cfg, params, x, bias)[3]) == 1536
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+def vg(f):
+    return jax.jit(jax.value_and_grad(
+        lambda p, x_: jnp.sum(jnp.sin(f(p, x_))), argnums=(0, 1)))
+want = vg(lambda p, x_: dense_oracle(cfg, p, x_, bias))(params, x)
+placed = {k: jax.device_put(v, NamedSharding(
+    mesh, P("model") if k.startswith("w_") else P())) for k, v in params.items()}
+seen.clear()
+with jax.set_mesh(mesh), sharding_env(mesh):
+    got = vg(lambda p, x_: apply_moe(cfg, p, x_, bias)[0])(placed, x)
+print("seen", sorted(set(seen)))
+for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+print("ok")
+'''
+
+
+def test_held_experts_expert_parallel_match_dense_oracle():
+    """The chunked layer with its four held experts split over a 2-way
+    model axis (two a shard, megablox's group offset), every pick held so
+    that two chunks run: the loss and every gradient match the dense
+    oracle; four emulated CPU devices, Pallas interpreter."""
+    out = run_py(_EXPERT_PARALLEL_MOE, devices=4)
+    assert "seen [(1024, 2)]" in out and out.rstrip().endswith("ok")

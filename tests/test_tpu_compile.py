@@ -35,6 +35,7 @@ from repro.kernels.quantize.kernel import dequantize_fwd, quantize_fwd
 from repro.kernels.rmsnorm.kernel import rmsnorm_fwd
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 from repro.models.model import Model
+from repro.models.moe import chunk_rows
 from repro.train.steps import build_train_step, init_train_state
 
 OLMO = get_config("olmo-1b")
@@ -404,7 +405,9 @@ def test_moonlight_step_with_host_optimizer_compiles(one_chip, monkeypatch):
     step's shard_map, manual over the axes the step leaves automatic), a
     leading dense layer swept in row blocks, AdamW state in pinned host:
     the TPU compiler takes it. Host arrays [d, 576] or [d, 16, 192] would
-    be refused here (lane-slice write-backs of their row blocks)."""
+    be refused here (lane-slice write-backs of their row blocks). The
+    expert dispatch holds chunks of held picks, not a row for every
+    pick."""
     import repro.kernels.gates as gates
     monkeypatch.setattr(compat, "host_memory_kind", lambda: "pinned_host")
     monkeypatch.setattr(gates, "_on_tpu", lambda: True)
@@ -433,3 +436,13 @@ def test_moonlight_step_with_host_optimizer_compiles(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 6      # gmm and tgmm
     for scope in ("/attn.mla/", "/moe.experts/", "/lms.opt_sweep/"):
         assert scope in text, scope
+    # 12 grouped matmuls a layer: 3 forward, 9 in the backward (the chunk
+    # recomputed, the rows' and the weights' gradients); the remat of the
+    # layer's forward drops the unused chunk loop
+    assert len(re.findall(r"%t?gmm\.\d+ = ", text)) == 12
+    # the dispatch holds chunks of C = 512 held picks, never a row for each
+    # of the T*k = 512 x 6 picks
+    assert chunk_rows(cfg, 512) == 512
+    assert "[512,1408]" in text
+    for rows in ("[3072,2048]", "[3072,1408]"):
+        assert rows not in text, rows
