@@ -32,7 +32,8 @@ for topo in (True, False):
     assert float(out["w"][0, 0]) == 1.0  # mean of 4 identical replicas
 print()
 print("Both schedules produce identical gradients; DDL moves only 1/|data|")
-print("of the bytes across the slow cross-pod fabric (see bench_ddl_allreduce).")
+print("of the bytes across the slow cross-pod fabric (priced by")
+print("core/ddl/topology.py's ddl_allreduce_time).")
 """
 
 

@@ -14,11 +14,10 @@ Two passes, both CI gates:
   with per-rule codes and an inline waiver syntax.
 
 `run` drives both over every step builder and writes
-`analysis_report.json` for Planner v2 / CI artifacts.
+`analysis_report.json` as a CI artifact.
 """
-from repro.analysis.report import (AnalysisReport, Finding, StepAudit,
-                                   load_analysis_report, step_plan_deltas)
+from repro.analysis.report import AnalysisReport, Finding, StepAudit
 from repro.analysis.jaxpr_audit import audit_step, aval_fingerprint
 
 __all__ = ["AnalysisReport", "Finding", "StepAudit", "audit_step",
-           "aval_fingerprint", "load_analysis_report", "step_plan_deltas"]
+           "aval_fingerprint"]

@@ -20,13 +20,13 @@ actually does). Finding codes (DESIGN.md §11):
                                (erases the quantization capacity win)
   JXA005 peak-over-budget      liveness peak estimate exceeds the planner's
                                priced budget (warning: the linear estimate
-                               overcounts vs XLA; the delta feeds Planner v2)
+                               overcounts vs XLA; the delta is reported)
 
 The liveness walk is a deliberate *over*-estimate: eqn-order liveness
 with inner scan/jit peaks folded in at their call sites, no rematerial-
 ization or scheduling freedom. It bounds what XLA can possibly hold live,
-which is the number Planner v2 wants to reconcile its static pricing
-against (analysis_report.json carries the delta per step).
+which is the number to reconcile the planner's static pricing against
+(analysis_report.json carries the delta per step).
 """
 from __future__ import annotations
 
@@ -345,7 +345,7 @@ def audit_step(name: str, fn, args: Sequence,
             f"liveness peak estimate {peak / 2**20:.1f} MiB exceeds the "
             f"planner budget {budget_bytes / 2**20:.1f} MiB "
             f"(delta {(peak - budget_bytes) / 2**20:+.1f} MiB) — "
-            "reconcile with MemoryPlan pricing (Planner v2 input)",
+            "reconcile with MemoryPlan pricing",
             name, severity="warning",
             data={"peak_live_bytes": peak, "budget_bytes": budget_bytes}))
     n_eqns = sum(1 for _ in iter_eqns(inner))

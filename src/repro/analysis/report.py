@@ -1,11 +1,10 @@
 """Finding / report containers shared by the jaxpr auditor and the lint
-pass, plus the `analysis_report.json` writer CI uploads and Planner v2
-consumes (DESIGN.md §11).
+pass, plus the `analysis_report.json` writer CI uploads (DESIGN.md §11).
 
 Severity contract: only unwaived ``error`` findings gate CI. ``warning``
 is advisory (e.g. the peak-live-bytes estimate exceeding the planner's
 budget — the linear-liveness estimate deliberately overcounts vs XLA's
-scheduler, so the delta is data for Planner v2, not a hard failure).
+scheduler, so the delta is data, not a hard failure).
 """
 from __future__ import annotations
 
@@ -42,7 +41,7 @@ class Finding:
 @dataclass
 class StepAudit:
     """The auditor's account of one jitted step: findings plus the
-    machine-readable sizing Planner v2 reconciles against its own pricing
+    machine-readable sizing reconciled against the planner's pricing
     (peak_live_bytes is the jaxpr liveness estimate; plan_peak_bytes /
     budget_bytes come from the MemoryPlan that priced this step)."""
     name: str
@@ -123,24 +122,3 @@ class AnalysisReport:
                      "waived/advisory")
         return "\n".join(lines)
 
-
-def load_analysis_report(path: str) -> Dict:
-    """Read ``analysis_report.json`` back as a calibration input, validating
-    the keys Planner v2 consumes (the per-step audits with their JXA005
-    ``plan_delta_bytes``). Raises ValueError on a file that is not an
-    analysis report."""
-    from repro.core.lms.costmodel import validate_analysis_report
-    with open(path) as f:
-        return validate_analysis_report(json.load(f))
-
-
-def step_plan_deltas(report: Dict) -> Dict[str, int]:
-    """{step name: plan_delta_bytes} for every audited step that was priced
-    against a plan — the live-bytes margins CostModel.live_margin folds
-    back into calibrated budgets."""
-    out: Dict[str, int] = {}
-    for s in report.get("steps", []):
-        d = s.get("plan_delta_bytes")
-        if d is not None and s.get("name"):
-            out[str(s["name"])] = int(d)
-    return out

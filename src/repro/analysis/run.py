@@ -9,7 +9,7 @@ slot-batched serve decode in model-width / int8 / int8+paged-arena
 variants — runs every jaxpr-audit check on each, runs the repo lint
 pass, and verifies the recompile sentinel (all slot-churn scenarios map
 to ONE step signature: JXA006 if not). Exit 1 on any gating finding;
-the JSON report is the artifact CI uploads and Planner v2 consumes.
+the JSON report is the artifact CI uploads.
 
 Everything here is backend-free: no compile, no weights, runs on the
 CPU-only CI runner in seconds.

@@ -268,8 +268,7 @@ class DDLConfig:
     mode: str = "allreduce"           # "allreduce" (paper) | "zero1" (beyond) | "none"
     compress_dcn: bool = False        # int8 + error feedback on pod hop
     # gradient bucketing for overlap. None = auto: the executor's default
-    # 64 MiB, or the calibrated plan's tuned_bucket_mb when a Planner v2
-    # profile priced one. An explicit integer always wins over the planner.
+    # 64 MiB (core/ddl/overlap.DEFAULT_BUCKET_MB).
     bucket_mb: Optional[int] = None
     topology_aware: bool = True       # False => flat NCCL-style single all-reduce
     # per-layer reduction inside the backward scan (core/ddl/overlap.py)

@@ -70,16 +70,13 @@ from repro.core.ddl.allreduce import (_leaf_is_replicated, ddl_reduce_leaf,
 from repro.obs import get_obs
 
 
-# executor default when DDLConfig.bucket_mb is None (auto) and no
-# calibrated plan tuned it
+# executor default when DDLConfig.bucket_mb is None (auto)
 DEFAULT_BUCKET_MB = 64
 
 
 def _bucket_elems(cfg: DDLConfig) -> int:
     """DDLConfig.bucket_mb in f32 elements (reductions run in f32).
-    bucket_mb=None means auto — the step builders substitute a calibrated
-    plan's tuned_bucket_mb before the cfg reaches here; untouched it is the
-    executor default."""
+    bucket_mb=None means auto: the executor default."""
     mb = DEFAULT_BUCKET_MB if cfg.bucket_mb is None else int(cfg.bucket_mb)
     return max(mb * (1 << 20) // 4, 1)
 
@@ -197,7 +194,7 @@ def reduce_tree_bucketed(ct, cfg: DDLConfig, *, data_axis: str,
     if buckets:
         # trace-time accounting (fires once per layer-group trace, not per
         # execution): bucket count + f32 reduction bytes for this layer's
-        # cotangent — the overlap report's collective track
+        # cotangent
         _obs = get_obs()
         _obs.trace_event("ddl.bucket", buckets=len(buckets),
                          bytes=4 * sum(sizes), keep=keep)

@@ -33,8 +33,7 @@ def _record_swap(site: str, tree, cls: str) -> None:
     """Trace-time swap accounting (DESIGN.md §12): the stream helpers run
     inside jitted scan bodies, so this fires once per TRACE (one layer's
     tensors = the plan's swap unit), not once per execution — recorded as
-    kind="trace" events plus per-residency-class byte counters, and kept
-    out of the wall-clock overlap math by the report."""
+    kind="trace" events plus per-residency-class byte counters."""
     obs = get_obs()
     nbytes = _tree_bytes(tree)
     obs.trace_event(site, bytes=nbytes, cls=cls)
@@ -72,8 +71,8 @@ def stream_layer_to_device(layer_params, *, cls: str = "params"):
     stays numerically byte-identical to the resident graph.
 
     `cls`: the plan residency class being streamed ("params", "optimizer",
-    "grads", "kvcache") — labels the trace-time swap accounting so the
-    overlap report can break bytes down per class."""
+    "grads", "kvcache") — labels the trace-time swap accounting per
+    class."""
     _record_swap("lms.swap_in", layer_params, cls)
     return compat.to_memory_kind(layer_params, effective_kind(DEVICE))
 

@@ -10,25 +10,20 @@ on TPU the host link is ~25x slower than HBM, so the planner offloads only
 when the swap is overlappable with a layer's compute
 (swap_time <= layer_compute_time) and prefers remat otherwise.
 
-Planner v2 (DESIGN.md §13): the unified entry point is
-``plan(PlanRequest(...), profile=...)``. Without a profile it reproduces the
-v1 static pricing exactly; with one (an ``obs_report.json`` path, its dict,
-or a prebuilt `CostModel`) the remat-vs-swap-vs-resident choice, the
-prefetch depth, the serve pool's staging depth and the DDL bucket size are
-all re-derived from MEASURED bandwidth/overlap and the jaxpr auditor's
-live-bytes margins. ``plan_memory`` / ``plan_serve_memory`` remain as thin
-deprecated wrappers over the facade.
+Every plan is priced from one source: the hardware constants of the
+`HardwareSpec` it is made for (`hw.py`; by default the attached chip). The
+entry point is ``plan(PlanRequest(...))``; ``plan_memory`` /
+``plan_serve_memory`` are thin wrappers over it.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple
 
 from repro import hw as hwlib
 from repro.config.base import LMSConfig, MeshSpec, ModelConfig, ShapeConfig
-from repro.core.lms.costmodel import CostModel
 
 
 @dataclass
@@ -264,13 +259,6 @@ class MemoryPlan:
     # serve plans only: the paged-pool sizing that EXECUTES kvcache host
     # residency (required by check_schedule_invariant when serve=True)
     kv_paging: Optional[KVPagingPlan] = None
-    # Planner v2: True iff a measured CostModel priced this plan (peak then
-    # includes the audited live-bytes margin; tuned knobs below are set)
-    calibrated: bool = False
-    # calibrated DDL gradient-bucket size; None = leave DDLConfig's default.
-    # Consumed by the step builders only when DDLConfig.bucket_mb is None
-    # (auto) — an explicit user bucket always wins.
-    tuned_bucket_mb: Optional[int] = None
 
     def summary(self) -> str:
         gb = 1024 ** 3
@@ -295,10 +283,6 @@ class MemoryPlan:
         if self.overlap_grads is not None:
             lines.append(f"  grad reduction: "
                          f"{'overlapped' if self.overlap_grads else 'serialized'}")
-        if self.calibrated:
-            lines.append(f"  calibrated: yes"
-                         + (f" (DDL bucket {self.tuned_bucket_mb} MiB)"
-                            if self.tuned_bucket_mb else ""))
         lines += [f"  note: {n}" for n in self.notes]
         return "\n".join(lines)
 
@@ -689,32 +673,14 @@ class PlanRequest:
     kv_dtype: str = "model"
 
 
-def _as_cost(profile, hw: hwlib.HardwareSpec) -> Optional[CostModel]:
-    """Normalize the `profile` argument: None stays None (pure v1 pricing),
-    a CostModel passes through, a dict is an in-memory obs_report, anything
-    else is an obs_report.json path."""
-    if profile is None:
-        return None
-    if isinstance(profile, CostModel):
-        return profile
-    if isinstance(profile, dict):
-        return CostModel.from_reports(profile, hw=hw)
-    return CostModel.load(str(profile), hw=hw)
-
-
-def plan(request: PlanRequest,
-         profile: Union[None, CostModel, dict, str] = None) -> MemoryPlan:
-    """Unified planning facade (Planner v2, DESIGN.md §13): one entry point
-    for train, inference and serve plans. `profile` optionally calibrates
-    the pricing — a `CostModel`, an obs_report dict, or an obs_report.json
-    path; None reproduces the v1 static-constant plan bit for bit. With no
-    `hw` the plan is for the attached chip (its real HBM limit on TPU)."""
+def plan(request: PlanRequest) -> MemoryPlan:
+    """One entry point for train, inference and serve plans. With no `hw`
+    the plan is for the attached chip (its real HBM limit on TPU)."""
     if request.hw is None:
         request = dataclasses.replace(request, hw=hwlib.attached())
-    cost = _as_cost(profile, request.hw)
     if request.serve:
-        return _plan_serve(request, cost)
-    return _plan_memory(request, cost)
+        return _plan_serve(request)
+    return _plan_memory(request)
 
 
 def plan_serve_memory(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
@@ -732,7 +698,7 @@ def plan_serve_memory(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
                             kv_dtype=kv_dtype))
 
 
-def _plan_serve(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
+def _plan_serve(req: PlanRequest) -> MemoryPlan:
     """Serving-engine plan (continuous batching over `slots` decode slots
     with a `backlog_slots`-deep admission queue): decode-shape residency
     PLUS the paged-pool sizing that executes kvcache host residency.
@@ -747,15 +713,7 @@ def _plan_serve(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
     rules, page_size, kv_dtype = req.rules, req.page_size, req.kv_dtype
     if shape.kind != "decode":
         raise ValueError(f"serve plans are decode-shaped, got {shape.kind!r}")
-    budget_full = (lms.hbm_budget or hw.hbm_bytes)
-    budget_full = int(budget_full * (1.0 - lms.workspace_frac))
-    cal = cost is not None and cost.calibrated
-    # audited live-bytes feedback (JXA005): the margin the jaxpr auditor
-    # measured past the plan's pricing tightens the working budget and is
-    # charged back into the reported peak, so a calibrated plan's
-    # plan_delta_bytes can only shrink
-    margin = cost.live_margin("decode") if cal else 0
-    budget = budget_full - margin
+    budget = int((lms.hbm_budget or hw.hbm_bytes) * (1.0 - lms.workspace_frac))
     tp = _axis_size(mesh, "model")
     dp = _axis_size(mesh, "data") * _axis_size(mesh, "pod")
     b = max(shape.global_batch // dp, 1)
@@ -763,11 +721,6 @@ def _plan_serve(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
     backlog = req.backlog_slots if req.backlog_slots is not None else 2 * slots
     L = cfg.num_layers
     notes: List[str] = []
-    if cal:
-        notes.append(cost.describe())
-    if margin:
-        notes.append(f"budget tightened by audited live-bytes margin "
-                     f"{margin / 2**20:.1f} MiB (JXA005 plan_delta feedback)")
     class_swap: Dict[str, int] = {}
     residency = {"params": "device", "kvcache": "device"}
 
@@ -814,31 +767,15 @@ def _plan_serve(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
         if lms.enabled:
             notes.append("aggregate KV fits: pool not required")
 
-    peak = params_eff + kv_dev + transient
+    peak = int(params_eff + kv_dev + transient)
     swap_per_step = sum(class_swap.values())
-    staging_depth = 2
-    if cal and paging is not None and residency.get("kvcache") == "host":
-        # calibrated pool staging: how many released-slot page returns the
-        # engine keeps in flight, sized from the MEASURED kvcache bandwidth
-        # against the mean decode tick instead of the fixed double-buffer
-        slot_bytes = (paging.pages_per_slot * paging.page_bytes
-                      + paging.state_bytes)
-        staging_depth = cost.tune_staging_depth(slot_bytes)
-        if staging_depth != 2:
-            notes.append(
-                f"pool staging depth tuned 2 -> {staging_depth} "
-                f"(kvcache at {cost.bw('kvcache') / 1e9:.2f} GB/s measured "
-                f"vs mean decode tick)")
     schedule = make_swap_schedule(residency, L, "decode",
-                                  prefetch_depth=staging_depth,
                                   swap_bytes=class_swap)
     check_schedule_invariant(residency, schedule, serve=True,
                              kv_paging=paging)
-    peak = int(peak) + margin
-    return MemoryPlan({}, residency, int(peak), int(host), int(swap_per_step),
-                      budget_full, peak <= budget_full, notes,
-                      swap_schedule=schedule, kv_paging=paging,
-                      calibrated=cal)
+    return MemoryPlan({}, residency, peak, int(host), int(swap_per_step),
+                      budget, peak <= budget, notes,
+                      swap_schedule=schedule, kv_paging=paging)
 
 
 def plan_memory(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
@@ -853,27 +790,14 @@ def plan_memory(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
                             microbatches=microbatches))
 
 
-def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
+def _plan_memory(req: PlanRequest) -> MemoryPlan:
     cfg, shape, mesh, lms, hw = req.cfg, req.shape, req.mesh, req.lms, req.hw
     optimizer, zero1, rules = req.optimizer, req.zero1, req.rules
     microbatches = req.microbatches
-    budget_full = (lms.hbm_budget or hw.hbm_bytes)
-    budget_full = int(budget_full * (1.0 - lms.workspace_frac))
-    cal = cost is not None and cost.calibrated
-    # audited live-bytes feedback (JXA005): tighten the working budget by
-    # the margin the jaxpr auditor measured past this kind's plan pricing,
-    # and charge it back into the reported peak — a calibrated plan's
-    # plan_delta_bytes can only shrink vs the uncalibrated one
-    margin = cost.live_margin(shape.kind) if cal else 0
-    budget = budget_full - margin
+    budget = int((lms.hbm_budget or hw.hbm_bytes) * (1.0 - lms.workspace_frac))
     tp = _axis_size(mesh, "model")
     dp = _axis_size(mesh, "data")
     notes: List[str] = []
-    if cal:
-        notes.append(cost.describe())
-    if margin:
-        notes.append(f"budget tightened by audited live-bytes margin "
-                     f"{margin / 2**20:.1f} MiB (JXA005 plan_delta feedback)")
 
     n_params = cfg.param_count()
     params_dev = 2 * n_params // tp                       # bf16, TP-sharded
@@ -901,10 +825,8 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
         peak = params_dev + kv + transient
         host = 0
         if not lms.enabled:
-            peak += margin
-            return MemoryPlan({}, residency, peak, 0, 0, budget_full,
-                              peak <= budget_full, notes + ["LMS disabled"],
-                              calibrated=cal)
+            return MemoryPlan({}, residency, peak, 0, 0, budget,
+                              peak <= budget, notes + ["LMS disabled"])
         if peak > budget and lms.offload_params != "never":
             # stream params per layer: keep 2 layers resident
             resident = 2 * params_dev // max(L, 1)
@@ -925,11 +847,9 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
         schedule = make_swap_schedule(residency, L, shape.kind,
                                       swap_bytes=class_swap)
         check_schedule_invariant(residency, schedule)
-        peak = int(peak) + margin
         return MemoryPlan({}, residency, int(peak), int(host),
-                          int(swap_per_step), budget_full,
-                          peak <= budget_full, notes,
-                          swap_schedule=schedule, calibrated=cal)
+                          int(swap_per_step), budget, peak <= budget, notes,
+                          swap_schedule=schedule)
 
     # ---- training -----------------------------------------------------------
     acts = activation_classes(cfg, shape, mesh)
@@ -1044,23 +964,6 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
             for a in sorted(others, key=lambda a: -a.bytes_dev):
                 if peak_now() <= budget:
                     break
-                if cal:
-                    # joint remat-vs-swap at MEASURED cost (Planner v2):
-                    # the un-hidden swap remainder plus the dispatch tax
-                    # (exactly the fig2b evaluator's expression) against
-                    # the recompute time — take the cheaper escape instead
-                    # of the v1 "offload iff fully overlappable" threshold
-                    off_s = cost.exposed_swap_s(2 * a.bytes_dev,
-                                                "activations", layer_time)
-                    remat_s = (a.recompute_flops / hw.peak_flops_bf16
-                               if lms.remat else float("inf"))
-                    if off_s <= remat_s:
-                        assignment[a.name] = "offload"
-                        host += L * a.bytes_dev
-                        swap_per_step += 2 * L * a.bytes_dev
-                    else:
-                        assignment[a.name] = "remat"
-                    continue
                 swap_time = 2 * a.bytes_dev / hw.host_bw
                 if swap_time <= layer_time:
                     assignment[a.name] = "offload"
@@ -1083,49 +986,6 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
         peak = peak_now()
     else:
         peak = fixed() + saved_bytes()
-        params_dev_eff = params_dev
-
-    # ---- calibrated knob tuning (Planner v2) --------------------------------
-    prefetch_depth = 2
-    tuned_bucket_mb = None
-    if cal and lms.enabled:
-        streamed = [c for c in STREAM_CLASSES
-                    if residency.get(c) == "host"
-                    and not (zero1 and c == "optimizer")]
-        if streamed:
-            # depth so the slowest measured stream keeps up with compute;
-            # the extra resident layer slices it costs are re-fit against
-            # the budget (back off to smaller divisors of L if they spill)
-            per_layer = {c: class_swap.get(c, 0) / max(2 * L, 1)
-                         for c in streamed}
-            worst = max(streamed, key=lambda c: per_layer[c] / cost.bw(c))
-            want = cost.tune_prefetch_depth(L, per_layer[worst], layer_time,
-                                            cls_name=worst)
-            # the optimizer sweep moves one unit at a time at any depth:
-            # its working set does not grow with the depth
-            inc = {"params": 2 * params_dev // max(L, 1),
-                   "optimizer": 0,
-                   "grads": grads_dev // max(L, 1),
-                   "kvcache": 0}
-            extra = sum(inc.get(c, 0) for c in streamed)
-            for d in sorted((c for c in range(2, min(8, L) + 1)
-                             if L % c == 0 and c <= want), reverse=True):
-                if peak + (d - 2) * extra <= budget:
-                    prefetch_depth = d
-                    break
-            if prefetch_depth != 2:
-                peak += (prefetch_depth - 2) * extra
-                notes.append(
-                    f"prefetch depth tuned 2 -> {prefetch_depth} ({worst} "
-                    f"stream at {cost.bw(worst) / 1e9:.2f} GB/s measured vs "
-                    f"{layer_time * 1e3:.2f} ms/layer; "
-                    f"+{(prefetch_depth - 2) * extra / 2**20:.0f} MiB "
-                    f"resident)")
-        if dp * _axis_size(mesh, "pod") > 1 and bool(overlap_grads):
-            tuned_bucket_mb = cost.tune_bucket_mb(2.0 * layer_time)
-            notes.append(f"DDL bucket tuned to {tuned_bucket_mb} MiB (one "
-                         f"bucket's fabric time hides behind one backward "
-                         f"layer at {layer_time * 1e3:.2f} ms/layer)")
 
     # zero1 executes optimizer-host residency as a flat P("data")-sharded
     # placement (the 1/|data| shard moves wholesale around its update) —
@@ -1135,19 +995,15 @@ def _plan_memory(req: PlanRequest, cost: Optional[CostModel]) -> MemoryPlan:
                       if zero1 and residency.get("optimizer") == "host"
                       else ())
     schedule = make_swap_schedule(residency, L, shape.kind,
-                                  prefetch_depth=prefetch_depth,
                                   overlap_grads=bool(overlap_grads),
                                   swap_bytes=class_swap,
                                   placement_only=placement_only)
     check_schedule_invariant(residency, schedule, placement_only)
-    peak = int(peak) + margin
     return MemoryPlan(assignment, residency, int(peak), int(host),
-                      int(swap_per_step), budget_full,
-                      peak <= budget_full, notes,
+                      int(swap_per_step), budget, peak <= budget, notes,
                       swap_schedule=schedule,
                       overlap_grads=overlap_grads,
-                      placement_only=placement_only,
-                      calibrated=cal, tuned_bucket_mb=tuned_bucket_mb)
+                      placement_only=placement_only)
 
 
 def hbm_traffic_model(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,

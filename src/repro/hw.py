@@ -38,25 +38,6 @@ TPU_V5E = HardwareSpec(
     vmem_bytes=128 * 1024**2,
 )
 
-# The paper's platform, kept for the fidelity benchmarks (bench_lms_overhead):
-# IBM AC922, V100-16GB over NVLink 2.0 (3 bricks, ~75 GB/s/dir aggregated per GPU
-# in the 6-GPU config; 150 GB/s in the 4-GPU config) vs PCIe gen3 (~12 GB/s eff).
-V100_NVLINK = HardwareSpec(
-    name="v100-nvlink2",
-    peak_flops_bf16=125e12,          # V100 tensor-core fp16
-    hbm_bytes=16 * 1024**3,
-    hbm_bw=900e9,
-    ici_link_bw=25e9, ici_links=6,   # GPU<->GPU NVLink
-    dcn_bw=12.5e9,                   # 100 Gb/s InfiniBand
-    host_bw=150e9,                   # CPU<->GPU NVLink 2.0 (the paper's enabler)
-    host_bytes=1024 * 1024**3,
-    vmem_bytes=96 * 1024,            # SM shared memory (unused; GPU analogue)
-)
-
-V100_PCIE = V100_NVLINK.__class__(
-    **{**V100_NVLINK.__dict__, "name": "v100-pcie3", "host_bw": 12e9}
-)
-
 DEFAULT = TPU_V5E
 
 # jax.Device.device_kind -> spec

@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache. Called by the entry
-points (launch/train.py, launch/serve.py, chip_smoke.py, benchmarks/run.py),
-never at import time.
+points (launch/train.py, launch/serve.py, chip_smoke.py), never at import
+time.
 
 `JAX_COMPILATION_CACHE_DIR`, when set, is JAX's own setting and is left to
 it. Otherwise the cache lives at a fixed path in the checkout,

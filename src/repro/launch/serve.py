@@ -19,13 +19,11 @@ import numpy as np
 
 from repro.config.base import MeshSpec, ShapeConfig
 from repro.configs import get_config, get_smoke_config
-from repro.core.lms.planner import PlanRequest, plan as plan_lms
 from repro.launch.cache import configure_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import kvquant
 from repro.models.model import Model
-from repro.obs import (configure, get_obs, profiler_trace,
-                       write_obs_report)
+from repro.obs import configure, get_obs, profiler_trace
 from repro.serve import (ServeEngine, decode_step_batch,
                          static_batch_from_requests, synth_requests)
 from repro.train.steps import (StepSpec, build_decode_step,
@@ -105,16 +103,7 @@ def main(argv=None):
                    help="run the engine under the JAX profiler, writing its "
                         "trace (host spans and device ops on one clock) "
                         "under this directory")
-    p.add_argument("--obs-report", default="",
-                   help="write the overlap/swap obs report JSON at exit")
-    p.add_argument("--profile", default="",
-                   help="Planner v2 calibration: size the paged pool and "
-                        "staging depth from the measured bandwidths in this "
-                        "obs_report.json (a prior run's --obs-report output)")
     args = p.parse_args(argv)
-    if args.static and args.profile:
-        p.error("--profile plans the engine's paged pool; the --static "
-                "baseline loop is unplanned")
     configure_compile_cache()
     if args.static and (args.temperature > 0 or args.top_k):
         p.error("--temperature/--top-k sample in the engine only; the "
@@ -144,17 +133,8 @@ def main(argv=None):
     obs = get_obs()
     total = args.prompt_len + args.gen
     slots = min(args.slots, args.requests)
-    plan = None
-    if args.profile:
-        plan = plan_lms(PlanRequest(
-            cfg=cfg, shape=ShapeConfig("cli_serve", "decode", total,
-                                       args.requests),
-            mesh=mesh_spec, serve=True, slots=slots,
-            page_size=args.page_size, kv_dtype=args.kv_dtype),
-            profile=args.profile)
-        print(plan.summary())
     eng = ServeEngine(model, mesh, slots=slots,
-                      max_len=total, plan=plan, page_size=args.page_size,
+                      max_len=total, page_size=args.page_size,
                       prefill_chunk=args.prefill_chunk,
                       temperature=args.temperature, top_k=args.top_k,
                       kv_dtype=args.kv_dtype, obs=obs)
@@ -173,9 +153,6 @@ def main(argv=None):
           np.asarray(results[reqs[0].rid])[:16])
     if args.trace:
         print(f"profiler trace: {args.trace}")
-    if args.obs_report:
-        write_obs_report(args.obs_report, obs=eng.obs)
-        print(f"obs report: {args.obs_report}")
     print("-- metrics --")
     for line in eng.obs.registry.summary_lines():
         print(line)

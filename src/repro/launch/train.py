@@ -16,8 +16,7 @@ from repro.config.base import (DDLConfig, LMSConfig, MeshSpec, ShapeConfig,
                                TrainConfig)
 from repro.configs import get_config, get_smoke_config
 from repro.launch.cache import configure_compile_cache
-from repro.obs import (TelemetryLoop, configure, get_obs, profiler_trace,
-                       write_obs_report)
+from repro.obs import TelemetryLoop, configure, get_obs, profiler_trace
 from repro.runtime import (FaultEvent, FaultInjector, FaultPlan,
                            RestartPolicy, Supervisor)
 from repro.train.trainer import Trainer
@@ -64,13 +63,6 @@ def main(argv=None):
                    help="run under the JAX profiler, writing its trace "
                         "(host spans and device ops on one clock) under "
                         "this directory")
-    p.add_argument("--obs-report", default="",
-                   help="write the overlap/swap obs report JSON at exit")
-    p.add_argument("--profile", default="",
-                   help="Planner v2 calibration: plan from the measured "
-                        "bandwidths/overlap in this obs_report.json (a "
-                        "prior run's --obs-report output) instead of "
-                        "hardware constants")
     p.add_argument("--spike-action", default="off",
                    choices=["off", "record", "stop"],
                    help="loss-spike telemetry: record alerts, or stop the "
@@ -146,10 +138,7 @@ def main(argv=None):
         else:
             trainer = Trainer(tcfg, heartbeat_dir=args.heartbeat_dir or None,
                               injector=injector, obs=obs,
-                              telemetry=telemetry,
-                              profile=args.profile or None)
-            if trainer.plan is not None and trainer.plan.calibrated:
-                print(trainer.plan.summary())
+                              telemetry=telemetry)
             state, hist = trainer.train(steps=args.steps, on_step=log)
     if args.trace:
         print(f"profiler trace: {args.trace}")
@@ -160,9 +149,6 @@ def main(argv=None):
         for a in telemetry.alerts:
             print(f"telemetry alert: {a}")
     print(f"final loss: {hist[-1]['loss']:.4f} (from {hist[0]['loss']:.4f})")
-    if args.obs_report:
-        write_obs_report(args.obs_report, obs=obs)
-        print(f"obs report: {args.obs_report}")
     print("-- metrics --")
     for line in obs.registry.summary_lines():
         print(line)

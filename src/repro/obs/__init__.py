@@ -1,7 +1,7 @@
 """Unified runtime observability (DESIGN.md §12): metrics registry,
 tracing spans over a bounded ring + JSONL sink (each span also a
-`jax.profiler` trace annotation once jax is imported), the overlap report,
-and the training telemetry loop.
+`jax.profiler` trace annotation once jax is imported), and the training
+telemetry loop.
 
 Dependency-free by design (stdlib only at import — no jax, no numpy): every
 layer of the system imports this package without ordering hazards, and the
@@ -9,8 +9,6 @@ jaxpr auditor sees zero new primitives from instrumentation.
 """
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 Series)
-from repro.obs.report import (build_obs_report, categorize, load_obs_report,
-                              overlap_report, write_obs_report)
 from repro.obs.sites import SITE_PREFIXES, SITE_RE, check_site
 from repro.obs.telemetry import SpikeDetector, TelemetryAlert, TelemetryLoop
 from repro.obs.trace import (Obs, SpanEvent, TraceRing, configure, get_obs,
@@ -19,8 +17,6 @@ from repro.obs.trace import (Obs, SpanEvent, TraceRing, configure, get_obs,
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Series",
-    "build_obs_report", "categorize", "load_obs_report", "overlap_report",
-    "write_obs_report",
     "SITE_PREFIXES", "SITE_RE", "check_site",
     "SpikeDetector", "TelemetryAlert", "TelemetryLoop",
     "Obs", "SpanEvent", "TraceRing", "configure", "get_obs", "instant",

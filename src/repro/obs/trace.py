@@ -9,8 +9,8 @@ ops it dispatched (with the profiler off that costs one TraceMe check).
 ``instant(site)`` emits a zero-duration point event. ``trace_event(site)``
 is the variant for code that runs at JIT *trace* time (the LMS swap stream
 helpers, the DDL bucket builder): it fires once per trace, not once per
-execution, so the report treats its events as plan-shaped byte accounting
-and keeps them OUT of the wall-clock overlap math (kind="trace").
+execution, so its events are plan-shaped byte accounting with no time
+(kind="trace").
 
 An ``Obs`` bundles a ``MetricsRegistry`` with a ring. The module-level
 default (``get_obs()``/``configure()``) is what free-standing helpers
@@ -41,7 +41,7 @@ from repro.obs.sites import check_site
 class SpanEvent:
     """One timeline event. ``t0``/``dur`` are monotonic seconds; ``kind``
     is "span" (timed region), "instant" (point event), or "trace"
-    (JIT-trace-time accounting, excluded from overlap math)."""
+    (JIT-trace-time accounting, no time)."""
     site: str
     t0: float
     dur: float
@@ -174,7 +174,7 @@ class Obs:
 
     def trace_event(self, site: str, **attrs) -> SpanEvent:
         """Point event emitted at JIT trace time (fires once per trace, not
-        per execution) — byte/plan accounting, excluded from overlap math."""
+        per execution) — byte/plan accounting, no time."""
         check_site(site)
         ev = SpanEvent(site, time.monotonic(), 0.0, "trace", self._depth(),
                        threading.get_ident(), dict(attrs))

@@ -75,7 +75,7 @@ class ServeEngine:
         self.temperature, self.top_k = temperature, top_k
         self.seed, self.eos_id = seed, eos_id
         # per-engine Obs: a PRIVATE metrics registry (two engines in one
-        # process — bench_serve — must not cross-contaminate counters) over
+        # process must not cross-contaminate counters) over
         # the process-global span ring (one unified timeline for the trace)
         self.obs = obs if obs is not None else Obs()
         # robustness knobs: stall_rounds bounds consecutive no-progress
@@ -125,14 +125,6 @@ class ServeEngine:
          cache_sh) = build_slot_decode_step(
             model, shape, mesh,
             spec=dataclasses.replace(spec, arena=arena))
-        # staging window for the spill double buffer: a CALIBRATED plan
-        # that streams the KV class carries a measured-bandwidth-tuned
-        # prefetch depth; a static plan keeps the legacy one-ahead buffer
-        sched = plan.swap_schedule if plan is not None else None
-        self._stage_depth = (max(1, sched.prefetch_depth)
-                             if plan is not None and plan.calibrated
-                             and sched is not None
-                             and "kvcache" in sched.stream else 1)
         self.pool = PagedKVPool(model, slots=slots, max_len=max_len,
                                 page_size=page_size,
                                 device_pages=device_pages,
@@ -155,6 +147,7 @@ class ServeEngine:
                        else 0)
         # host-resident params (a plan that streams them) swap in per layer
         # in the prefill scans too
+        sched = plan.swap_schedule if plan is not None else None
         if self._chunk:
             self._scratch = model.init_cache(1, max_len)
             self._chunk_fn = jax.jit(
@@ -465,19 +458,13 @@ class ServeEngine:
         return progressed
 
     def _prefetch_next(self) -> None:
-        """Double buffer: stage the next waiting requests' spilled pages
-        back toward the device while the decode tick computes. Stages up to
-        `_stage_depth` requests per call (1 unless a calibrated plan tuned
-        the window deeper); stops early when the device budget refuses a
-        claim — deeper staging cannot proceed past an exhausted budget."""
-        staged = 0
+        """Double buffer: stage the first waiting request whose pages are
+        spilled back toward the device while the decode tick computes (the
+        pool declines when the device budget refuses the claim)."""
         for req in self.scheduler.queue:
             if self.pool.status(req.rid) == "host":
-                if not self.pool.prefetch(req.rid):
-                    return
-                staged += 1
-                if staged >= self._stage_depth:
-                    return
+                self.pool.prefetch(req.rid)
+                return
 
     # ---- decode -----------------------------------------------------------
     def _fail_active(self, reason: str) -> None:
@@ -513,9 +500,6 @@ class ServeEngine:
             toks[s, 0] = r.tokens[-1]
             pos[s] = request_prompt_len(self.cfg, r) + len(r.tokens) - 1
             act[s] = True
-        # the tick span is a COMPUTE interval for the overlap report: pool
-        # prefetch/release spans nesting inside it are swap work hidden
-        # under decode
         with self.obs.span("engine.tick", batch=len(active)):
             posd = jnp.asarray(pos)
             batch = decode_step_batch(self.cfg, jnp.asarray(toks), posd)

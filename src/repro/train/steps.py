@@ -34,7 +34,6 @@ layer).
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
@@ -46,7 +45,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
-from repro.config.base import DDLConfig, ShapeConfig, TrainConfig
+from repro.config.base import ShapeConfig, TrainConfig
 from repro.core.ddl.allreduce import (ddl_reduce_tree,
                                       hierarchical_reduce_scatter_flat,
                                       pack, pack_spec, unpack, PackSpec)
@@ -123,16 +122,6 @@ class StepSpec:
         if kv_paging is not None:
             return kvquant.validate_kv_dtype(kv_paging.kv_dtype)
         return "model"
-
-    def ddl_for(self, tcfg: TrainConfig) -> DDLConfig:
-        """The DDL config the step executes with: a calibrated plan's
-        tuned_bucket_mb substitutes for bucket_mb=None (auto); an explicit
-        user bucket always wins."""
-        if (tcfg.ddl.bucket_mb is None and self.plan is not None
-                and self.plan.calibrated and self.plan.tuned_bucket_mb):
-            return dataclasses.replace(tcfg.ddl,
-                                       bucket_mb=self.plan.tuned_bucket_mb)
-        return tcfg.ddl
 
 
 # TPU compiler options for a step whose decoder scan carries the DDL
@@ -518,7 +507,7 @@ def build_train_step(model: Model, tcfg: TrainConfig, mesh,
                         overlap_grads=overlap_grads)
     plan, donate, rules = spec.plan, spec.donate, spec.rules
     overlap_grads = spec.overlap_grads
-    ddl = spec.ddl_for(tcfg)
+    ddl = tcfg.ddl
     cfg = model.cfg
     sizes = mesh_axis_sizes(mesh)
     dpa = dp_axes(mesh)
@@ -788,7 +777,7 @@ def build_zero1_train_step(model: Model, tcfg: TrainConfig, mesh,
     if spec is None:
         spec = StepSpec(plan=plan, donate=donate)
     plan, donate = spec.plan, spec.donate
-    ddl = spec.ddl_for(tcfg)
+    ddl = tcfg.ddl
     cfg = model.cfg
     sizes = mesh_axis_sizes(mesh)
     dpa = dp_axes(mesh)

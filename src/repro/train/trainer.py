@@ -39,8 +39,7 @@ class Trainer:
     def __init__(self, tcfg: TrainConfig, *, attn_impl: str = "blockwise",
                  process: int = 0, heartbeat_dir: Optional[str] = None,
                  injector=None, obs: Optional[Obs] = None,
-                 telemetry: Optional[TelemetryLoop] = None,
-                 profile=None):
+                 telemetry: Optional[TelemetryLoop] = None):
         self.tcfg = tcfg
         # private registry over the shared span ring (same pattern as the
         # serve engine); a supplied telemetry loop records its alerts here
@@ -50,13 +49,11 @@ class Trainer:
             telemetry.obs = self.obs
         self.mesh = make_mesh(tcfg.mesh)
         self.model = Model(tcfg.model, attn_impl=attn_impl)
-        # profile: a Planner v2 calibration source (obs_report.json path,
-        # loaded dict, or CostModel) — None plans from hardware constants
         self.plan = (plan_lms(PlanRequest(
                         cfg=tcfg.model, shape=tcfg.shape, mesh=tcfg.mesh,
                         lms=tcfg.lms, optimizer=tcfg.optimizer,
                         zero1=(tcfg.ddl.mode == "zero1"),
-                        microbatches=tcfg.microbatches), profile=profile)
+                        microbatches=tcfg.microbatches))
                      if tcfg.lms.enabled else None)
         self.process = process
         self._inj = injector

@@ -91,7 +91,7 @@ def test_peak_estimate_and_budget_warning_jxa005():
     jxa5 = [x for x in a.findings if x.code == "JXA005"]
     assert all(x.severity == "warning" for x in jxa5)
     assert not [x for x in a.findings if x.gating], \
-        "the budget reconciliation is advisory (Planner v2 input), not a gate"
+        "the budget reconciliation is advisory, not a gate"
     assert a.peak_live_bytes >= 16 * 16 * 4  # at least the input stays live
 
 

@@ -1,7 +1,6 @@
 """Observability layer (DESIGN.md §12): metrics registry semantics, span
-nesting + ring bounds, JSONL sink, spans as profiler host events, overlap
-report on synthetic spans, spike detection, and the trainer's spans and
-log_every flush."""
+nesting + ring bounds, JSONL sink, spans as profiler host events, spike
+detection, and the trainer's spans and log_every flush."""
 import glob
 import json
 import os
@@ -12,9 +11,8 @@ import numpy as np
 import pytest
 
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Obs,
-                       SpanEvent, SpikeDetector, TelemetryAlert,
-                       TelemetryLoop, TraceRing, build_obs_report,
-                       categorize, check_site, overlap_report)
+                       SpikeDetector, TelemetryAlert, TelemetryLoop,
+                       TraceRing, check_site)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -137,82 +135,6 @@ def test_jsonl_sink(tmp_path):
     assert [r["site"] for r in rows] == ["test.a", "test.b"]
     assert rows[0]["kind"] == "span" and rows[1]["kind"] == "instant"
     assert rows[0]["attrs"] == {"n": 1}
-
-
-# ---------------------------------------------------------------------------
-# overlap report
-
-
-def _ev(site, t0, dur, kind="span", **attrs):
-    return SpanEvent(site, t0, dur, kind, 0, 0, attrs)
-
-
-def test_overlap_frac_synthetic():
-    # compute [0, 10); swap [2, 4) hides fully, swap [12, 14) not at all
-    events = [
-        _ev("engine.tick", 0.0, 10.0, step=7),
-        _ev("lms.swap_in", 2.0, 2.0, cls="params", bytes=100),
-        _ev("pool.prefetch", 12.0, 2.0, cls="kvcache", bytes=50),
-    ]
-    r = overlap_report(events)
-    assert r["overlap_frac"] == pytest.approx(0.5)
-    assert r["swap_s"] == pytest.approx(4.0)
-    assert r["overlapped_s"] == pytest.approx(2.0)
-    assert r["swap_spans"] == 2 and r["compute_spans"] == 1
-    (row,) = r["per_step"]
-    assert row["step"] == 7                    # attrs step wins over index
-    assert row["swap_overlap_s"] == pytest.approx(2.0)
-    assert row["overlap_frac"] == pytest.approx(0.2)
-
-
-def test_overlap_mutually_overlapping_swaps_not_double_counted():
-    events = [
-        _ev("engine.tick", 0.0, 10.0),
-        _ev("lms.swap_in", 2.0, 4.0),          # [2, 6)
-        _ev("lms.swap_out", 4.0, 4.0),         # [4, 8) — overlaps the first
-    ]
-    r = overlap_report(events)
-    # per-step hidden time uses the UNION of swap intervals: [2, 8) = 6s
-    assert r["per_step"][0]["swap_overlap_s"] == pytest.approx(6.0)
-
-
-def test_trace_events_excluded_from_wallclock_but_counted_in_classes():
-    events = [
-        _ev("engine.tick", 0.0, 10.0),
-        _ev("lms.swap_in", 0.0, 0.0, kind="trace", cls="params", bytes=512),
-        _ev("pool.spill", 1.0, 2.0, cls="kvcache", bytes=128),
-    ]
-    r = overlap_report(events)
-    assert r["swap_spans"] == 1                # the trace event is not a span
-    assert r["swap_s"] == pytest.approx(2.0)
-    cls = r["classes"]
-    assert cls["params"] == {"bytes": 512, "events": 1, "span_s": 0.0,
-                             "trace_events": 1, "bytes_per_s": None}
-    assert cls["kvcache"]["bytes"] == 128
-    assert cls["kvcache"]["bytes_per_s"] == pytest.approx(64.0)
-
-
-def test_categorize():
-    assert categorize("engine.tick") == "compute"
-    assert categorize("train.step") == "compute"
-    assert categorize("lms.swap_in") == "swap"
-    assert categorize("pool.prefetch") == "swap"
-    assert categorize("ddl.bucket") == "collective"
-    assert categorize("ckpt.save") == "other"
-
-
-def test_build_obs_report_shape():
-    obs = _iso_obs()
-    with obs.span("engine.tick"):
-        with obs.span("pool.spill", cls="kvcache", bytes=64):
-            pass
-    obs.registry.counter("engine.ticks").inc()
-    r = build_obs_report(obs, meta={"mode": "test"})
-    assert r["schema"] == 1 and r["events"] == 2
-    assert r["event_kinds"]["span"] == 2
-    assert r["swap_spans"] == 1 and "overlap_frac" in r
-    assert r["registry"]["counters"]["engine.ticks"] == 1.0
-    assert r["meta"] == {"mode": "test"}
 
 
 # ---------------------------------------------------------------------------
